@@ -91,7 +91,10 @@ def run_fsdp_overlap():
     improvement, and zero sharded-anchor state bytes."""
     probe = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "fsdp_overlap_probe.py")
+    # the probe is a CPU-only count probe: pin its child to the CPU so it
+    # never asks for an accelerator this process may already hold
     proc = subprocess.run([sys.executable, probe, "--check"],
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"},
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
         raise RuntimeError(f"fsdp_overlap probe failed:\n{proc.stdout}\n"

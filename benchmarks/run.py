@@ -10,6 +10,8 @@ import json
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "bench_norms", "bench_variance", "bench_convergence", "bench_sublinear",
     "bench_multimachine", "bench_localsgd", "bench_nn",
@@ -47,14 +49,17 @@ def main(argv=None) -> None:
                    help="comma-separated benchmark module names")
     args = p.parse_args(argv)
     names = [m for m in args.modules.split(",") if m]
+    enable_compile_cache()
     summary = run_modules(names)
     if set(names) == set(MODULES):
-        # roofline table (requires dry-run results; skipped gracefully
-        # otherwise; not part of the machine-readable summary)
+        # roofline table (prints a note and returns when there are no
+        # dry-run results; a failure fails the run like any module)
         try:
             from benchmarks import roofline
             roofline.main()
         except Exception:
+            summary["failed"].append("roofline")
+            summary["ok"] = False
             traceback.print_exc()
     print("BENCH_JSON " + json.dumps(summary))
     if summary["failed"]:

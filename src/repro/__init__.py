@@ -1,9 +1,7 @@
 """Reproduction of "New Bounds For Distributed Mean Estimation and Variance
 Reduction" (ICLR 2021) grown into a jax_pallas training/serving system.
 
-Importing ``repro`` installs small jax forward-compat aliases (see
-:mod:`repro._compat`) so the sources — written against the current
-``jax.shard_map`` / ``jax.sharding.AxisType`` API — also run on the pinned
-0.4.x jax in the CI image.
+Written against jax 0.9 (``jax.shard_map``, ``jax.sharding.AxisType``,
+``jax.make_mesh(axis_types=...)``); importing ``repro`` has no side effects
+on jax.
 """
-from repro import _compat as _compat  # noqa: F401  (side-effect: jax shims)

@@ -54,12 +54,16 @@ def _fwht_kernel(x_ref, ha_ref, hb_ref, o_ref, *, a: int, b: int, scale: float):
     bm = x.shape[0]
     x3 = x.reshape(bm, a, b)
     # right-multiply by H_b  : (bm, a, b) x (b, b) -> (bm, a, b)
+    # HIGHEST: the MXU's default single bf16 pass would round every input
+    # and the intermediate t to 8 mantissa bits
     t = jax.lax.dot_general(x3, hb_ref[...],
                             (((2,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     # left-multiply by H_a   : contract axis 1 (H symmetric) -> (bm, b, a)
     t = jax.lax.dot_general(t, ha_ref[...],
                             (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     t = jnp.swapaxes(t, 1, 2)                    # (bm, a, b)
     o_ref[...] = (t.reshape(bm, a * b) * scale).astype(o_ref.dtype)

@@ -38,24 +38,39 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 COLS = 2048
 DEFAULT_BLOCK_ROWS = 8
 
 
-def _decode_math(w, anchor, u, s, *, q: int, bits: int,
-                 avg_cnt: Optional[int], coords: bool, ref=None):
-    """Shared decode body: packed words (..., COLS//per) -> k or z (..., COLS).
+def _unpack(w, ct_ref, *, q: int, bits: int):
+    """Packed words (rows, COLS//per) -> int32 colors (rows, COLS).
 
-    anchor/u/s broadcast against the unpacked colors (the batched kernel
-    passes (bs, bm, COLS) words against a (bm, COLS) anchor block).  ``ref``
+    The inverse of the encode kernel's pack, built from the ops Mosaic
+    lowers: the words are transposed (lanes -> sublanes), field i of every
+    word is written to rows i, i+per, ... of the (COLS, rows) scratch by one
+    sublane-strided store, and the scratch is transposed back.  The scratch
+    holds one int32 per color, where a per-field broadcast would pad each
+    word's ``per`` fields out to a full 128-lane row of VMEM."""
+    per = 32 // bits
+    wt = jax.lax.bitcast_convert_type(w, jnp.int32).T
+    n_words = wt.shape[0]
+    for i in range(per):
+        ct_ref[pl.ds(i, n_words, stride=per), :] = \
+            jnp.bitwise_and(wt >> (i * bits), q - 1)
+    return ct_ref[...].T
+
+
+def _decode_math(c, anchor, u, s, *, q: int, avg_cnt: Optional[int],
+                 coords: bool, ref=None):
+    """Shared decode body: int32 colors (..., COLS) -> k or z (..., COLS).
+
+    anchor/u/s broadcast against the colors (the batched kernel passes
+    (bs, bm, COLS) colors against a (bm, COLS) anchor block).  ``ref``
     is the QState anchor the sender subtracted before encoding: the
     coordinate frame becomes anchor-relative, ``k_a = round((a - ref)/s - u)``
     and the decoded point gets ``ref`` added back."""
-    shifts = (jnp.arange(per := 32 // bits, dtype=jnp.uint32)
-              * jnp.uint32(bits))
-    c = ((w[..., :, None] >> shifts) & jnp.uint32(q - 1)).astype(jnp.int32)
-    c = c.reshape(w.shape[:-1] + (w.shape[-1] * per,))  # (..., COLS) colors
     av = anchor - ref if ref is not None else anchor
     t = av / s - u
     k_a = jnp.round(t).astype(jnp.int32)
@@ -75,15 +90,15 @@ def _decode_kernel(w_ref, a_ref, u_ref, s_ref, *refs, q: int, bits: int,
                    avg_cnt: Optional[int], scalar_s: bool, coords: bool,
                    with_ref: bool):
     if with_ref:
-        r_ref, o_ref = refs
+        r_ref, o_ref, ct_ref = refs
         rv = r_ref[...]
     else:
-        (o_ref,) = refs
+        o_ref, ct_ref = refs
         rv = None
     s = s_ref[0, 0] if scalar_s else s_ref[...]
-    out = _decode_math(w_ref[...], a_ref[...].astype(jnp.float32), u_ref[...],
-                       s, q=q, bits=bits, avg_cnt=avg_cnt, coords=coords,
-                       ref=rv)
+    c = _unpack(w_ref[...], ct_ref, q=q, bits=bits)
+    out = _decode_math(c, a_ref[...].astype(jnp.float32), u_ref[...], s, q=q,
+                       avg_cnt=avg_cnt, coords=coords, ref=rv)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -145,6 +160,7 @@ def lattice_decode_pallas(words: jax.Array, anchor: jax.Array, u: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, COLS), out_dtype),
+        scratch_shapes=[pltpu.VMEM((COLS, bm), jnp.int32)],
         interpret=interpret,
     )(*in_arrays)
     return out.reshape(-1)[:n]
@@ -157,10 +173,10 @@ def _decode_batched_kernel(w_ref, a_ref, u_ref, s_ref, *refs, q: int,
                            bits: int, s_kind: str, coords: bool,
                            with_ref: bool):
     if with_ref:
-        r_ref, o_ref = refs
+        r_ref, o_ref, ct_ref = refs
         rv = r_ref[...]                     # (bm, COLS), broadcasts over bs
     else:
-        (o_ref,) = refs
+        o_ref, ct_ref = refs
         rv = None
     if s_kind == "scalar":
         s = s_ref[0, 0]
@@ -168,9 +184,10 @@ def _decode_batched_kernel(w_ref, a_ref, u_ref, s_ref, *refs, q: int,
         s = s_ref[...]                      # (bm, COLS), broadcasts over bs
     else:                                   # per-sender: (bs, bm, COLS)
         s = s_ref[...]
-    out = _decode_math(w_ref[...], a_ref[...].astype(jnp.float32), u_ref[...],
-                       s, q=q, bits=bits, avg_cnt=None, coords=coords,
-                       ref=rv)
+    bs, bm, n_words = w_ref.shape
+    c = _unpack(w_ref[...].reshape(bs * bm, n_words), ct_ref, q=q, bits=bits)
+    out = _decode_math(c.reshape(bs, bm, -1), a_ref[...].astype(jnp.float32),
+                       u_ref[...], s, q=q, avg_cnt=None, coords=coords, ref=rv)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -187,10 +204,10 @@ def lattice_decode_batched_pallas(words: jax.Array, anchor: jax.Array,
     """Decode (senders, n_words) packed payloads against one anchor (n,).
 
     One pallas_call over a (sender_tiles, row_tiles) grid; each step holds a
-    (block_senders, block_rows, COLS) tile in VMEM (~2.5 MiB at the
-    defaults), decoding ``block_senders`` payloads against one anchor block
-    read once per tile.  The per-sender words (the 8x-compressed payload)
-    dominate HBM traffic.  ``s`` is a scalar, a shared (n,) per-coordinate
+    (block_senders, block_rows, COLS) tile in VMEM, decoding
+    ``block_senders`` payloads against one anchor block read once per
+    tile.  The per-sender words (the 8x-compressed payload) dominate HBM
+    traffic.  ``s`` is a scalar, a shared (n,) per-coordinate
     array, or a per-sender (senders, n) array (each sender's sides
     sidecar).  ``ref`` (n,) is the shared QState anchor all senders
     subtracted before encoding (fused like the anchor block, read once per
@@ -248,6 +265,7 @@ def lattice_decode_batched_pallas(words: jax.Array, anchor: jax.Array,
         out_specs=pl.BlockSpec((bs, bm, COLS), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((senders + spad, rows, COLS),
                                        out_dtype),
+        scratch_shapes=[pltpu.VMEM((COLS, bs * bm), jnp.int32)],
         interpret=interpret,
     )(*in_arrays)
     return out.reshape(senders + spad, -1)[:senders, :n]

@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 COLS = 2048
 DEFAULT_BLOCK_ROWS = 8
@@ -50,16 +51,22 @@ def _encode_kernel(x_ref, u_ref, s_ref, *refs, q: int, bits: int,
     else:
         o_refs = refs
         xv = x_ref[...].astype(jnp.float32)
+    *o_refs, ct_ref = o_refs
     s = s_ref[0, 0] if scalar_s else s_ref[...]
     t = xv / s - u_ref[...]
     k = jnp.round(t).astype(jnp.int32)
-    c = jnp.bitwise_and(k, q - 1).astype(jnp.uint32)      # mod q (q = 2^bits')
-    bm, ccols = c.shape
+    # Pack: word j of a row holds colors j*per .. j*per+per-1.  Mosaic has
+    # no lane-splitting reshape and no lane-strided load, so the colors are
+    # transposed into VMEM scratch (lanes -> sublanes), field i of every
+    # word is one sublane-strided load, and the OR of the shifted fields is
+    # transposed back.  Fields are disjoint, so OR == the packed sum.
+    ct_ref[...] = jnp.bitwise_and(k, q - 1).T        # mod q (q = 2^bits')
     per = 32 // bits
-    c = c.reshape(bm, ccols // per, per)
-    shifts = (jnp.arange(per, dtype=jnp.uint32) * jnp.uint32(bits))
-    # fields are disjoint -> sum == bitwise OR, and sum reduces cleanly on TPU
-    o_refs[0][...] = jnp.sum(c << shifts, axis=-1, dtype=jnp.uint32)
+    n_words = ct_ref.shape[0] // per
+    acc = ct_ref[pl.ds(0, n_words, stride=per), :]
+    for i in range(1, per):
+        acc = acc | (ct_ref[pl.ds(i, n_words, stride=per), :] << (i * bits))
+    o_refs[0][...] = jax.lax.bitcast_convert_type(acc.T, jnp.uint32)
     if with_coords:
         o_refs[1][...] = k
 
@@ -123,6 +130,7 @@ def lattice_encode_pallas(x: jax.Array, u: jax.Array, s: jax.Array,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((COLS, bm), jnp.int32)],
         interpret=interpret,
     )(*in_arrays)
     n_words = (n + per - 1) // per

@@ -107,8 +107,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     # flops/collectives from the PRE-optimization HLO (dots are still dots;
     # the CPU backend rewrites big matmuls into oneDNN custom-calls in the
     # post-opt text); HBM-traffic proxy from the POST-opt (fused) HLO.

@@ -22,6 +22,7 @@ from repro.configs import registry
 from repro.models.config import ModelConfig
 from repro.models.sharding import ShardCtx
 from repro.dist.collectives import QSyncConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.train.trainer import Trainer, TrainConfig
 from repro.train.optim import OptConfig
@@ -59,6 +60,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.preset:
         cfg = PRESETS[args.preset]
