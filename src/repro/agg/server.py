@@ -132,7 +132,6 @@ class RoundStats:
     gave_up: int = 0             # clients dropped after escalation exhausted
     drains: int = 0
     bytes_in: int = 0
-    bytes_out: int = 0
     peak_unvalidated_bytes: int = 0   # largest frame staged before its CRC
     peak_pending_store_bytes: int = 0  # high-water of staged payload bodies
                                        # + reassembly-retained bytes (the
@@ -371,14 +370,16 @@ class AggServer:
         # the only bytes ever held before a CRC has vouched for them: this
         # one frame (<= header + MTU in a chunked round, whatever the d)
         self._obs.set_max("peak_unvalidated_bytes", len(data))
+        h = None
         try:
-            h, chunk = wire.decode_frame(data)
+            with _obs.span("agg.parse"):
+                h, chunk = wire.decode_frame(data)
+                wire.check_frame_against_spec(h, self.spec, len(chunk))
         except wire.WireError:
-            self._obs.inc("rejected_wire")
-            return self._respond(_reject(self.spec, 0xFFFFFFFF))
-        try:
-            wire.check_frame_against_spec(h, self.spec, len(chunk))
-        except wire.HeaderMismatchError:
+            if h is None:                   # the frame did not decode
+                self._obs.inc("rejected_wire")
+                return self._respond(_reject(self.spec, 0xFFFFFFFF))
+            # a well-formed frame of another round or configuration
             self._obs.inc("rejected_spec")
             return self._respond(_reject(self.spec, h.client_id,
                                          round_id=h.round_id))
@@ -416,7 +417,8 @@ class AggServer:
                 # stale chunk of an attempt this server already NACKed
                 self._obs.inc("duplicates")
                 return self._respond(self._queued(h, slim=True))
-            event, p = self._rx.add(h, chunk)
+            with _obs.span("agg.reassemble"):
+                event, p = self._rx.add(h, chunk)
             if event == S.REJECT:
                 # the reassembled body failed its payload-CRC seal (a
                 # forged chunk shared the stream's header): the stream is
@@ -441,8 +443,13 @@ class AggServer:
             if p.streamed:
                 # stream complete + payload-CRC sealed: verify and commit
                 # the speculative fold NOW — no staged body, nothing for
-                # the drain to carry
-                out = self._respond(self._finish_streamed(h, p))
+                # the drain to carry; the commit is this client's drain
+                with _obs.span("agg.commit", "drain",
+                               parent=("round", self.spec.round_id),
+                               round=self.spec.round_id,
+                               client=h.client_id):
+                    resp = self._finish_streamed(h, p)
+                out = self._respond(resp)
                 self._note_pending_store()
                 return out
         try:
@@ -492,9 +499,8 @@ class AggServer:
                              y_next=0.0, ack=ack, credit=self.spec.window)
 
     def _respond(self, r: wire.Response) -> bytes:
-        out = wire.encode_response(r)
-        self._obs.inc("bytes_out", len(out))
-        return out
+        with _obs.span("agg.respond"):
+            return wire.encode_response(r)
 
     # -------------------------------------------------------- STREAMING RX
     def _note_pending_store(self) -> None:
@@ -510,30 +516,34 @@ class AggServer:
         """``on_range_validated``: residual-fold one contiguous validated
         word range into the stream's speculative record; the session frees
         the chunk bytes as soon as this returns."""
-        key = (h.client_id, h.attempt, h.payload_crc)
-        rec = self._folds.get(key)
-        if rec is None:
-            rec = self._folds[key] = _StreamFold(self.spec.padded,
-                                                 self.spec.nb)
-        c0 = word_start * (32 // L.bits_for_q(h.q))
-        r = np.asarray(K.lattice_residuals_range(
-            jnp.asarray(words), self._k0, q=h.q, word_start=word_start))
-        n = r.shape[0]
-        rec.r[c0:c0 + n] = r.astype(np.int16)
-        rec.coords += n
-        k = r.astype(np.int64) + self._k0_np[c0:c0 + n]
-        part = np.sum(k.astype(np.uint32) * self._w_np[c0:c0 + n],
-                      dtype=np.uint32)
-        rec.check = (rec.check + int(part)) & 0xFFFFFFFF
-        if h.n_summed == 1:
-            # distance telemetry, masked to unit payloads like _drain_math
-            z = (k.astype(np.float32) + self._u_np[c0:c0 + n]) \
-                * self._s_np[c0:c0 + n]
-            dist = np.abs(z - self._ref_np[c0:c0 + n])
-            b = self.spec.cfg.bucket
-            bidx = np.arange(c0 // b, (c0 + n - 1) // b + 1)
-            mx = np.maximum.reduceat(dist, np.maximum(bidx * b - c0, 0))
-            rec.dist_b[bidx] = np.maximum(rec.dist_b[bidx], mx)
+        with _obs.span("agg.fold"):
+            key = (h.client_id, h.attempt, h.payload_crc)
+            rec = self._folds.get(key)
+            if rec is None:
+                rec = self._folds[key] = _StreamFold(self.spec.padded,
+                                                     self.spec.nb)
+            c0 = word_start * (32 // L.bits_for_q(h.q))
+            with _obs.span("agg.fold.residuals"):
+                r = np.asarray(K.lattice_residuals_range(
+                    jnp.asarray(words), self._k0, q=h.q,
+                    word_start=word_start))
+            n = r.shape[0]
+            rec.r[c0:c0 + n] = r.astype(np.int16)
+            rec.coords += n
+            k = r.astype(np.int64) + self._k0_np[c0:c0 + n]
+            part = np.sum(k.astype(np.uint32) * self._w_np[c0:c0 + n],
+                          dtype=np.uint32)
+            rec.check = (rec.check + int(part)) & 0xFFFFFFFF
+            if h.n_summed == 1:
+                # distance telemetry, masked to unit payloads like
+                # _drain_math
+                z = (k.astype(np.float32) + self._u_np[c0:c0 + n]) \
+                    * self._s_np[c0:c0 + n]
+                dist = np.abs(z - self._ref_np[c0:c0 + n])
+                b = self.spec.cfg.bucket
+                bidx = np.arange(c0 // b, (c0 + n - 1) // b + 1)
+                mx = np.maximum.reduceat(dist, np.maximum(bidx * b - c0, 0))
+                rec.dist_b[bidx] = np.maximum(rec.dist_b[bidx], mx)
 
     def _drop_stream(self, h: wire.FrameHeader) -> None:
         """``on_stream_discarded``: the rollback.  The record never touched
@@ -726,24 +736,32 @@ class AggServer:
         if not self._pending:
             return self._resend_requests()
         self._obs.inc("drains")
-        drain_sp = _obs.tracer().begin(
-            "drain", parent=("round", self.spec.round_id),
-            round=self.spec.round_id, payloads=len(self._pending)) \
-            if _obs.tracing_enabled() else None
-        by_q: dict[int, list[wire.Payload]] = {}
-        for p in self._pending.values():
-            by_q.setdefault(p.q, []).append(p)
-        self._pending.clear()
-        self._pending_bytes = 0
-        responses = []
-        for q, plist in sorted(by_q.items()):
-            plist.sort(key=lambda p: p.client_id)
-            # pad the sender axis to the kernel's block size so drain sizes
-            # map onto a bounded set of compiled shapes (padding rows carry
-            # valid=False and never enter the sum)
-            S = len(plist)
-            pad = (-S) % DEFAULT_BLOCK_SENDERS
-            attempt0 = plist[0].attempt
+        with _obs.span("agg.drain", "drain",
+                       parent=("round", self.spec.round_id),
+                       round=self.spec.round_id,
+                       payloads=len(self._pending)) as region:
+            by_q: dict[int, list[wire.Payload]] = {}
+            for p in self._pending.values():
+                by_q.setdefault(p.q, []).append(p)
+            self._pending.clear()
+            self._pending_bytes = 0
+            responses = []
+            for q, plist in sorted(by_q.items()):
+                responses += self._drain_q(q, plist)
+            region.note(accepted=len(self._accepted))
+        return responses + self._resend_requests()
+
+    def _drain_q(self, q: int, plist: "list[wire.Payload]") -> "list[bytes]":
+        """One batched decode of the pending payloads of color space ``q``;
+        returns their ACK/NACK/REJECT responses."""
+        plist.sort(key=lambda p: p.client_id)
+        # pad the sender axis to the kernel's block size so drain sizes map
+        # onto a bounded set of compiled shapes (padding rows carry
+        # valid=False and never enter the sum)
+        S = len(plist)
+        pad = (-S) % DEFAULT_BLOCK_SENDERS
+        attempt0 = plist[0].attempt
+        with _obs.span("agg.stage"):
             words = jnp.asarray(np.pad(
                 np.stack([p.words for p in plist]), ((0, pad), (0, 0))))
             sides = jnp.asarray(np.pad(
@@ -757,59 +775,62 @@ class AggServer:
                 constant_values=1))
             y_col = jnp.asarray(wire.y_buckets_at_attempt(self.spec,
                                                           attempt0))
+        with _obs.span("agg.decode"):
             (ok, ksum_delta, count_delta, max_dist, dist_b, fails_b,
              max_abs_k) = \
                 _drain_math(words, sides, checks, valid, self._ref_flat,
                             self._u.reshape(-1), self._weights, y_col, m,
                             self._k0, q=q, bucket=self.spec.cfg.bucket)
             ok = np.asarray(ok)[:S]
-            n_ok = int(ok.sum())
-            n_clients = int(count_delta)    # n_ok plus tier fan-in (m > 1)
-            # int32 accumulator guard: sum_i |k_i| <= count * max|k| must
-            # stay below 2^31 or the exact integer sum may have wrapped —
-            # fail loudly (an anchored round is the fix: coords stay ~y/s)
-            self._max_abs_k = max(self._max_abs_k, int(max_abs_k))
-            if (self._count + n_clients) * self._max_abs_k >= 2 ** 31:
-                raise OverflowError(
-                    f"round {self.spec.round_id}: accumulating {n_ok} more "
-                    f"senders with |coords| up to {self._max_abs_k} can "
-                    f"overflow the int32 sum ({self._count} accepted so "
-                    f"far); anchor the round (RoundSpec.anchor_digest) so "
-                    f"coordinates stay ~y/s instead of ~|x|/s")
-            self._ksum = self._ksum + ksum_delta.reshape(self._ksum.shape)
-            self._count += n_clients
-            self._obs.inc("accepted", n_ok)
-            self._obs.set_max("max_dist", float(max_dist))
-            self._stats.dist_b = np.maximum(self._stats.dist_b,
-                                            np.asarray(dist_b))
-            self._stats.fails_b = self._stats.fails_b + np.asarray(fails_b)
-            for p, good in zip(plist, ok):
-                if good:
-                    self._accepted.add(p.client_id)
-                    self._rx.discard(p.client_id)   # stale chunk sessions
-                    responses.append(self._respond(self._ack(p.client_id)))
-                    continue
-                self._obs.inc("decode_failures")
-                nxt = p.attempt + 1
-                if p.q >= wire.Q_CAP or nxt >= self.spec.max_attempts:
-                    self._gave_up.add(p.client_id)
-                    self._rx.discard(p.client_id)
-                    self._obs.inc("gave_up")
-                    responses.append(
-                        self._respond(_reject(self.spec, p.client_id)))
-                    continue
-                self._obs.inc("nacks_sent")
-                self._attempt_floor[p.client_id] = nxt
-                responses.append(self._respond(wire.Response(
-                    status=wire.STATUS_NACK, round_id=self.spec.round_id,
-                    client_id=p.client_id, attempt_next=nxt,
-                    q_next=wire.q_at_attempt(self.spec.cfg.q, nxt),
-                    y_next=wire.y_at_attempt(self.spec, nxt),
-                    y_buckets=self._margin_tuple(nxt),
-                    credit=self.spec.window)))
-        if drain_sp is not None:
-            _obs.tracer().end(drain_sp, accepted=len(self._accepted))
-        return responses + self._resend_requests()
+            n_clients = int(count_delta)    # accepts plus tier fan-in (m > 1)
+            max_abs_k = int(max_abs_k)
+            max_dist = float(max_dist)
+            dist_b = np.asarray(dist_b)
+            fails_b = np.asarray(fails_b)
+        n_ok = int(ok.sum())
+        # int32 accumulator guard: sum_i |k_i| <= count * max|k| must stay
+        # below 2^31 or the exact integer sum may have wrapped — fail loudly
+        # (an anchored round is the fix: coords stay ~y/s)
+        self._max_abs_k = max(self._max_abs_k, max_abs_k)
+        if (self._count + n_clients) * self._max_abs_k >= 2 ** 31:
+            raise OverflowError(
+                f"round {self.spec.round_id}: accumulating {n_ok} more "
+                f"senders with |coords| up to {self._max_abs_k} can "
+                f"overflow the int32 sum ({self._count} accepted so "
+                f"far); anchor the round (RoundSpec.anchor_digest) so "
+                f"coordinates stay ~y/s instead of ~|x|/s")
+        self._ksum = self._ksum + ksum_delta.reshape(self._ksum.shape)
+        self._count += n_clients
+        self._obs.inc("accepted", n_ok)
+        self._obs.set_max("max_dist", max_dist)
+        self._stats.dist_b = np.maximum(self._stats.dist_b, dist_b)
+        self._stats.fails_b = self._stats.fails_b + fails_b
+        responses = []
+        for p, good in zip(plist, ok):
+            if good:
+                self._accepted.add(p.client_id)
+                self._rx.discard(p.client_id)   # stale chunk sessions
+                responses.append(self._respond(self._ack(p.client_id)))
+                continue
+            self._obs.inc("decode_failures")
+            nxt = p.attempt + 1
+            if p.q >= wire.Q_CAP or nxt >= self.spec.max_attempts:
+                self._gave_up.add(p.client_id)
+                self._rx.discard(p.client_id)
+                self._obs.inc("gave_up")
+                responses.append(
+                    self._respond(_reject(self.spec, p.client_id)))
+                continue
+            self._obs.inc("nacks_sent")
+            self._attempt_floor[p.client_id] = nxt
+            responses.append(self._respond(wire.Response(
+                status=wire.STATUS_NACK, round_id=self.spec.round_id,
+                client_id=p.client_id, attempt_next=nxt,
+                q_next=wire.q_at_attempt(self.spec.cfg.q, nxt),
+                y_next=wire.y_at_attempt(self.spec, nxt),
+                y_buckets=self._margin_tuple(nxt),
+                credit=self.spec.window)))
+        return responses
 
     def _resend_for(self, cid: int, attempt: int, missing: tuple) -> bytes:
         self._obs.inc("resends_sent")

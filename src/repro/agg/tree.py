@@ -106,7 +106,6 @@ class TierStats:
     expired: int = 0
     drains: int = 0
     bytes_in: int = 0
-    bytes_out: int = 0
     up_frames_sent: int = 0      # upstream chunk frames (incl. retransmits)
     up_escalations: int = 0      # upstream NACKs honored (repack at next q)
     up_resends: int = 0          # upstream RESEND/timer retransmissions
@@ -479,9 +478,7 @@ class TierAggregator:
                              y_next=0.0, ack=ack, credit=self.spec.window)
 
     def _respond(self, r: wire.Response) -> bytes:
-        out = wire.encode_response(r)
-        self._obs.inc("bytes_out", len(out))
-        return out
+        return wire.encode_response(r)
 
     def _resend_requests(self) -> "list[bytes]":
         out = []
@@ -593,7 +590,6 @@ class TierAggregator:
 
     def _send_up(self, frames: "list[bytes]") -> "list[bytes]":
         self._obs.inc("up_frames_sent", len(frames))
-        self._obs.inc("bytes_out", sum(len(f) for f in frames))
         return frames
 
     def _upstream_tick(self) -> "list[bytes]":

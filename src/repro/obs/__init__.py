@@ -1,7 +1,8 @@
 """repro.obs — unified observability for kernels → transport → engine → tree.
 
-Zero-dependency (stdlib-only) metrics + tracing + flight recorder +
-exporters, OFF by default.  The switchboard:
+Metrics + tracing + flight recorder + exporters in the standard library,
+OFF by default, plus one span entry point on the JAX profiler's clock.
+The switchboard:
 
     import repro.obs as obs
     obs.enable()                      # metrics + tracing + flight recorder
@@ -23,17 +24,24 @@ Clock injection: ``enable(clock=time.monotonic)`` stamps spans with wall
 time; with no clock the tracer runs on virtual time fed by the open-loop
 sim's event loop (``tracer().feed_time(t)``), so exported traces share the
 event-time axis of the latency metrics.
+
+Timed regions of program code go through :func:`span`: while the JAX
+profiler collects, the region is a ``TraceAnnotation`` on the profiler's
+``/host:CPU`` plane, on the same clock as the device's ``XLA Ops``; while
+tracing is enabled it is also a :class:`Tracer` span under its own name.
 """
 from __future__ import annotations
 
 import itertools
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation as _TraceMe
+
 from . import export  # noqa: F401  (re-exported submodule)
 from .recorder import DEFAULT_CAPACITY, Dump, FlightRecorder  # noqa: F401
 from .registry import (DEFAULT_BOUNDS, NOOP, Counter, Gauge,  # noqa: F401
                        Histogram, Registry, Scope, quantile)
-from .trace import Span, Tracer, check_round  # noqa: F401
+from .trace import NO_REGION, Region, Span, Tracer, check_round  # noqa: F401
 
 _metrics_on = False
 _trace_on = False
@@ -105,6 +113,24 @@ def tracer() -> Tracer:
 
 def recorder() -> FlightRecorder:
     return _recorder
+
+
+def span(name: str, trace_name: Optional[str] = None, key=None, parent=None,
+         **attrs):
+    """A context manager timing one region of program code.
+
+    ``name`` is the region's profiler annotation: while the JAX profiler
+    collects, the region lands in its trace under that name.  ``trace_name``
+    names the region's :class:`Tracer` span (opened with ``key``, ``parent``
+    and ``attrs``; ``note(**attrs)`` on the region adds attributes recorded
+    when it closes) while tracing is enabled; without one the region is on
+    the profiler's clock only.  With neither collecting, the shared no-op
+    region comes back, at the cost of one profiler check."""
+    prof = _TraceMe.is_enabled()
+    if trace_name is None or not _trace_on:
+        return Region(_TraceMe(name)) if prof else NO_REGION
+    return Region(_TraceMe(name) if prof else None, _tracer,
+                  (trace_name, key, parent, attrs))
 
 
 def counter(name: str, **labels):

@@ -158,6 +158,62 @@ class Tracer:
         self._ids = itertools.count(1)
 
 
+class Region:
+    """One timed region of program code, opened by :func:`repro.obs.span`.
+
+    Enters ``annotation`` (a ``jax.profiler.TraceAnnotation``, or None when
+    the profiler is not collecting) and, when ``tracer`` is given, opens the
+    tracer span ``begin`` = (name, key, parent, attrs) inside it.
+    :meth:`note` adds attributes the tracer span records when it closes.
+    Slotted: the server opens several per arriving frame."""
+    __slots__ = ("_ann", "_tracer", "_begin", "_end_attrs", "_sp")
+
+    def __init__(self, annotation=None, tracer: Optional[Tracer] = None,
+                 begin: Optional[tuple] = None):
+        self._ann = annotation
+        self._tracer = tracer
+        self._begin = begin
+        self._end_attrs: dict = {}
+        self._sp: Optional[Span] = None
+
+    def __enter__(self) -> "Region":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            name, key, parent, attrs = self._begin
+            self._sp = self._tracer.begin(name, key=key, parent=parent,
+                                          **attrs)
+        return self
+
+    def note(self, **attrs) -> None:
+        self._end_attrs.update(attrs)
+
+    def __exit__(self, *exc) -> bool:
+        if self._sp is not None:
+            self._tracer.end(self._sp, **self._end_attrs)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+class _NoRegion:
+    """The shared region :func:`repro.obs.span` returns when neither the
+    profiler nor the tracer is collecting."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoRegion":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def note(self, **attrs) -> None:
+        pass
+
+
+NO_REGION = _NoRegion()
+
+
 def _under(tracer: Tracer, root_id: int) -> "list[Span]":
     """All spans in the subtree rooted at ``root_id``."""
     kids: dict = {}

@@ -15,7 +15,8 @@ import pytest
 
 import repro.obs as obs
 from repro.agg.server import AggServer
-from repro.agg.sim import OpenLoopConfig, fleet_payloads, run_open_loop
+from repro.agg.sim import (OpenLoopConfig, fleet_frames, fleet_payloads,
+                           run_open_loop)
 from repro.agg.transport import frame as wire
 from repro.agg.tree import AggTree
 from repro.dist.collectives import QSyncConfig
@@ -35,10 +36,11 @@ def _obs_clean():
 
 
 def _spec(round_id=1, d=256, bucket=64, q=16, seed=0, max_attempts=4,
-          mtu=0):
+          mtu=0, window=0):
     return wire.RoundSpec(round_id=round_id, d=d,
                           cfg=QSyncConfig(q=q, bucket=bucket), y0=0.5,
-                          seed=seed, max_attempts=max_attempts, mtu=mtu)
+                          seed=seed, max_attempts=max_attempts, mtu=mtu,
+                          window=window)
 
 
 def _fleet(spec, n, seed=0):
@@ -223,6 +225,88 @@ class TestSpanTrees:
         tr.feed_time(2.0)
         tr.end(("round", 1))             # second end is a no-op
         assert sp.end == 1.0
+
+
+# ------------------------------------------------------ obs.span entry point
+
+class _FakeTraceMe:
+    """Stands in for jax.profiler.TraceAnnotation: records each region
+    built while 'collecting' is set."""
+    collecting = False
+    built: list = []
+
+    def __init__(self, name):
+        _FakeTraceMe.built.append(name)
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.collecting
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    monkeypatch.setattr(_FakeTraceMe, "built", [])
+    monkeypatch.setattr(_FakeTraceMe, "collecting", False)
+    monkeypatch.setattr(obs, "_TraceMe", _FakeTraceMe)
+    return _FakeTraceMe
+
+
+class TestSpanEntryPoint:
+    def test_off_returns_the_shared_noop(self, fake_profiler):
+        assert obs.span("agg.parse") is obs.NO_REGION
+        assert obs.span("agg.drain", "drain", round=1) is obs.NO_REGION
+        with obs.span("agg.drain", "drain") as region:
+            region.note(accepted=3)
+        assert fake_profiler.built == [] and obs.tracer().spans == []
+
+    def test_tracer_span_under_its_own_name(self, fake_profiler):
+        obs.enable()
+        with obs.span("agg.drain", "drain", parent=("round", 4),
+                      round=4) as region:
+            region.note(accepted=2)
+        # a region with no tracer name stays off the tracer
+        assert obs.span("agg.parse") is obs.NO_REGION
+        (root, sp) = obs.tracer().spans
+        assert root.name == "round" and sp.name == "drain"
+        assert sp.parent_id == root.span_id and sp.end is not None
+        assert sp.attrs == {"round": 4, "accepted": 2}
+        assert fake_profiler.built == []
+
+    def test_profiler_annotation_beside_the_tracer_span(self, fake_profiler):
+        fake_profiler.collecting = True
+        with obs.span("agg.parse"):
+            pass
+        assert obs.tracer().spans == []
+        obs.enable()
+        with obs.span("agg.drain", "drain", key=("drain", 1)):
+            pass
+        assert fake_profiler.built == ["agg.parse", "agg.drain"]
+        assert obs.tracer().get(("drain", 1)).end is not None
+
+    @pytest.mark.parametrize("streaming", [True, False],
+                             ids=["streaming", "sealed"])
+    def test_chunked_round_complete_with_profiler_off(self, fake_profiler,
+                                                      streaming):
+        obs.enable()
+        spec = _spec(round_id=11, d=2048, bucket=256, mtu=300, window=2)
+        base, xs = _fleet(spec, 3)
+        server = AggServer(spec, base, streaming=streaming)
+        for frames in fleet_frames(spec, xs):
+            for f in frames:
+                server.ingest_frame(f)
+        server.seal()
+        server.tick()
+        pub = server.published()
+        assert pub and len(pub[0].accepted) == 3
+        assert check_round(obs.tracer(), spec.round_id,
+                           accepted=pub[0].accepted) == []
+        assert fake_profiler.built == []
 
 
 # --------------------------------------------------------------- exporters
