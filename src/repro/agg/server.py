@@ -17,23 +17,26 @@ drain the same zero-copy Payload view a single frame would have.
 seal-then-stage path above is replaced for multi-chunk payloads.  The
 session emits each stream's validated contiguous word prefix range by range
 (``on_range_validated``) and frees the chunk bytes; the server
-residual-folds every range on arrival (:func:`repro.kernels.ops.
-lattice_residuals_range` about the round's decode-reference coordinates
-``k0`` — the same integer identity the tree tiers use, so ``k0 + r`` is
-bit-for-bit what the batched decode would have produced) into a
-*speculative* per-stream record keyed by ``(client, attempt, payload_crc)``:
-int16 residuals, an incrementally-accumulated §5 checksum (h(k) is linear,
-so partial sums of ``w_i * k_i`` compose exactly), and per-bucket distance
-telemetry.  Nothing touches the round accumulator until the stream
-completes AND its payload-CRC seal + checksum verify — so "rollback" on a
-seal failure, escalation reset, eviction or expiry is simply dropping the
-record (``on_stream_discarded``), and the published mean stays
-bit-identical to the sealed drain under any arrival order, loss,
-duplication or escalation.  ``RoundStats.peak_pending_store_bytes`` gauges
-what the old path buffered: staged bodies + reassembly bytes — with the
-window holding senders near-in-order it stays far below one body per
-pending client.  Single-chunk payloads keep the batched path (they never
-had a body-sized backlog).
+residual-folds every range on arrival (the residuals about the round's
+decode-reference coordinates ``k0`` — the same integer identity the tree
+tiers use, so ``k0 + r`` is bit-for-bit what the batched decode would have
+produced) into a *speculative* per-stream record keyed by ``(client,
+attempt, payload_crc)``: an int16 residual vector that lives on the device.
+A range costs one asynchronous dispatch and no readback.  Nothing touches
+the round accumulator until the stream completes AND its payload-CRC seal
+holds; then one dispatch over the whole record computes its §5 checksum,
+its largest effective coordinate, its per-bucket distance telemetry and the
+candidate accumulator, and one readback of the small outputs gives the
+verdict.  So "rollback" on a seal failure, escalation reset, eviction or
+expiry is simply dropping the record (``on_stream_discarded``), and the
+published mean stays bit-identical to the sealed drain under any arrival
+order, loss, duplication or escalation.  ``RoundStats.fold_syncs`` counts
+the streaming fold's blocking readbacks (one per completed stream).
+``RoundStats.peak_pending_store_bytes`` gauges what the old path buffered:
+staged bodies + reassembly bytes — with the window holding senders
+near-in-order it stays far below one body per pending client.
+Single-chunk payloads keep the batched path (they never had a body-sized
+backlog).
 
 Chunked rounds add one response status: a drain that finds a client's
 reassembly still incomplete emits ``STATUS_RESEND`` naming exactly the
@@ -137,6 +140,8 @@ class RoundStats:
                                        # + reassembly-retained bytes (the
                                        # streaming drain shrinks this far
                                        # below one body per pending client)
+    fold_syncs: int = 0          # the streaming fold's blocking device-to-
+                                 # host readbacks (one per completed stream)
     max_dist: float = 0.0        # max |decoded - ref|_inf over accepts
     dist_b: Optional[np.ndarray] = None    # (nb,) per-bucket max distance
     fails_b: Optional[np.ndarray] = None   # (nb,) per-bucket failure counts
@@ -174,19 +179,61 @@ class _StreamFold:
     """Speculative per-stream fold for the streaming drain.
 
     One per open ``(client, attempt, payload_crc)`` stream identity: the
-    int16 residuals folded so far (|r| <= q/2 <= 2^15 at the q=2^16 packing
-    cap, so int16 always fits), the incrementally-accumulated §5 coordinate
-    checksum (h(k) is linear in k, so per-range partial sums of ``w_i *
-    k_i`` compose exactly mod 2^32), and per-bucket distance telemetry.
-    Nothing here has touched the round accumulator — dropping the record IS
-    the rollback."""
-    __slots__ = ("r", "check", "dist_b", "coords")
+    residuals folded so far, on the device as a (padded,) int16 vector
+    (|r| <= q/2 <= 2^15 at the q=2^16 packing cap, so int16 always fits),
+    and how many coordinates the folded ranges cover.  Nothing here has
+    touched the round accumulator — dropping the record IS the rollback."""
+    __slots__ = ("r", "coords")
 
-    def __init__(self, padded: int, nb: int):
-        self.r = np.zeros((padded,), np.int16)
-        self.check = 0          # the uint32 value, carried as a python int
-        self.dist_b = np.zeros((nb,), np.float32)
+    def __init__(self, padded: int):
+        self.r = jnp.zeros((padded,), jnp.int16)
         self.coords = 0
+
+
+@partial(jax.jit, static_argnames=("q", "n"), donate_argnums=(0,))
+def _fold_range_math(r: Array, words: Array, k0: Array, c0: Array, *,
+                     q: int, n: int) -> Array:
+    """Write the residuals of one validated word range into a stream record.
+
+    r: (padded,) int16 record (donated); words: (nw,) uint32 the packed words
+    covering coordinates ``[c0, c0 + n)``; k0: (padded,) int32 the round's
+    decode-reference coordinates; c0: () int32.  The same residuals as
+    :func:`repro.kernels.ops.lattice_residuals_range`, with the ``k0``
+    window sliced inside the jit."""
+    part = K.lattice_residuals(words, jax.lax.dynamic_slice(k0, (c0,), (n,)),
+                               q=q)
+    return jax.lax.dynamic_update_slice(r, part.astype(r.dtype), (c0,))
+
+
+@partial(jax.jit, static_argnames=("m", "bucket"))
+def _commit_math(r: Array, ksum: Array, k0: Array, weights: Array, u: Array,
+                 s: Array, ref: Array, *, m: int, bucket: int):
+    """A completed stream's verdict inputs and its candidate accumulator.
+
+    r: (padded,) int16 residual record; ksum: (nb, bucket) int32 the round
+    accumulator; k0/weights/u/s/ref: (padded,) the decode context (``s``
+    the sides repeated per coordinate); m: the payload's n_summed.  ``k = r
+    + k0`` is what the batched decode produces, so every output is
+    :func:`_drain_math`'s for a single sender: the §5 checksum of ``k``,
+    ``max|k_eff|`` with ``k_eff = k + (m-1)*k0``, the per-bucket distance
+    (the caller reads it for unit payloads only, m == 1), and ``ksum +
+    k_eff``, which the caller keeps only if the checksum and the int32
+    guard pass.
+
+    Returns (verdict (nb + 2,) uint32, ksum_new (nb, bucket) int32): the
+    verdict packs the checksum, ``max|k_eff|`` and the (nb,) float32
+    distances bit for bit into one vector, so the host reads it back in one
+    copy."""
+    k = r.astype(jnp.int32) + k0
+    check = ED.coord_checksum(k, weights)
+    k_eff = k + (m - 1) * k0
+    max_abs_k = jnp.max(jnp.abs(k_eff))
+    z = (k.astype(jnp.float32) + u) * s
+    dist_b = jnp.max(jnp.abs(z - ref).reshape(-1, bucket), axis=-1)
+    verdict = jnp.concatenate([
+        check[None], jax.lax.bitcast_convert_type(max_abs_k, jnp.uint32)[None],
+        jax.lax.bitcast_convert_type(dist_b, jnp.uint32)])
+    return verdict, ksum + k_eff.reshape(ksum.shape)
 
 
 @partial(jax.jit, static_argnames=("q", "bucket"))
@@ -310,18 +357,13 @@ class AggServer:
         self._pending: dict[int, wire.Payload] = {}
         self._pending_bytes = 0   # bodies staged for the batched drain
         self._folds: "dict[tuple, _StreamFold]" = {}
-        self._ksum_st: "Optional[np.ndarray]" = None  # (padded,) int64 —
-        #   the streamed commits, merged with _ksum at finalize
         self._streaming = ((spec.window > 0) if streaming is None
                            else bool(streaming)) and spec.mtu > 0
         if self._streaming:
-            # host-side mirrors of the decode context for per-range folds
-            self._k0_np = np.asarray(self._k0, np.int64)
-            self._w_np = np.asarray(self._weights)
-            self._u_np = np.asarray(self._u, np.float32).reshape(-1)
-            self._s_np = np.repeat(np.asarray(self._sides, np.float32),
-                                   spec.cfg.bucket)
-            self._ref_np = np.asarray(self._ref_flat, np.float32)
+            # the decode context per coordinate, for the streamed commit
+            self._u_flat = self._u.reshape(-1)
+            self._s_flat = jnp.repeat(self._sides, spec.cfg.bucket)
+            self._offsets: "dict[int, Array]" = {}   # fold offsets on device
             self._rx = S.Reassembler(spec,
                                      on_range_validated=self._fold_range,
                                      on_stream_discarded=self._drop_stream)
@@ -514,36 +556,33 @@ class AggServer:
     def _fold_range(self, h: wire.FrameHeader, word_start: int,
                     words: np.ndarray) -> None:
         """``on_range_validated``: residual-fold one contiguous validated
-        word range into the stream's speculative record; the session frees
-        the chunk bytes as soon as this returns."""
+        word range into the stream's speculative record on the device —
+        one asynchronous dispatch, nothing read back; the session frees the
+        chunk bytes as soon as this returns."""
         with _obs.span("agg.fold"):
             key = (h.client_id, h.attempt, h.payload_crc)
             rec = self._folds.get(key)
             if rec is None:
-                rec = self._folds[key] = _StreamFold(self.spec.padded,
-                                                     self.spec.nb)
-            c0 = word_start * (32 // L.bits_for_q(h.q))
+                rec = self._folds[key] = _StreamFold(self.spec.padded)
+            per = 32 // L.bits_for_q(h.q)
+            c0 = word_start * per
+            if c0 >= self.spec.padded:
+                raise ValueError(f"word_start {word_start} starts at "
+                                 f"coordinate {c0}, past the "
+                                 f"{self.spec.padded}-coordinate vector")
+            n = min(words.shape[-1] * per, self.spec.padded - c0)
+            c0_dev = self._offsets.get(c0)
+            if c0_dev is None:
+                # a spec's chunks fold at the same few offsets: keep each on
+                # the device, or every range pays a scalar's own copy there
+                c0_dev = self._offsets[c0] = jnp.asarray(c0, jnp.int32)
             with _obs.span("agg.fold.residuals"):
-                r = np.asarray(K.lattice_residuals_range(
-                    jnp.asarray(words), self._k0, q=h.q,
-                    word_start=word_start))
-            n = r.shape[0]
-            rec.r[c0:c0 + n] = r.astype(np.int16)
+                # the words go to the device as the jit's own argument (one
+                # copy, cheaper than a jnp.asarray first); nothing is read
+                # back
+                rec.r = _fold_range_math(rec.r, words, self._k0, c0_dev,
+                                         q=h.q, n=n)
             rec.coords += n
-            k = r.astype(np.int64) + self._k0_np[c0:c0 + n]
-            part = np.sum(k.astype(np.uint32) * self._w_np[c0:c0 + n],
-                          dtype=np.uint32)
-            rec.check = (rec.check + int(part)) & 0xFFFFFFFF
-            if h.n_summed == 1:
-                # distance telemetry, masked to unit payloads like
-                # _drain_math
-                z = (k.astype(np.float32) + self._u_np[c0:c0 + n]) \
-                    * self._s_np[c0:c0 + n]
-                dist = np.abs(z - self._ref_np[c0:c0 + n])
-                b = self.spec.cfg.bucket
-                bidx = np.arange(c0 // b, (c0 + n - 1) // b + 1)
-                mx = np.maximum.reduceat(dist, np.maximum(bidx * b - c0, 0))
-                rec.dist_b[bidx] = np.maximum(rec.dist_b[bidx], mx)
 
     def _drop_stream(self, h: wire.FrameHeader) -> None:
         """``on_stream_discarded``: the rollback.  The record never touched
@@ -577,11 +616,18 @@ class AggServer:
             _obs.tracer().event(
                 "seal", parent=("client", h.round_id, h.client_id),
                 round=h.round_id, client=h.client_id, attempt=h.attempt)
-        if rec.check != (h.check & 0xFFFFFFFF):
-            return self._nack_streamed(h, rec)
         m = h.n_summed
-        k_eff = rec.r.astype(np.int64) + m * self._k0_np
-        self._max_abs_k = max(self._max_abs_k, int(np.abs(k_eff).max()))
+        verdict, ksum = _commit_math(
+            rec.r, self._ksum, self._k0, self._weights, self._u_flat,
+            self._s_flat, self._ref_flat, m=m, bucket=self.spec.cfg.bucket)
+        # the stream's one blocking readback: the verdict's small inputs
+        verdict = np.asarray(verdict)
+        self._obs.inc("fold_syncs")
+        dist_b = verdict[2:].view(np.float32)
+        if int(verdict[0]) != (h.check & 0xFFFFFFFF):
+            return self._nack_streamed(h, dist_b)
+        self._max_abs_k = max(self._max_abs_k,
+                              int(verdict[1:2].view(np.int32)[0]))
         if (self._count + m) * self._max_abs_k >= 2 ** 31:
             raise OverflowError(
                 f"round {self.spec.round_id}: accumulating a streamed "
@@ -589,20 +635,19 @@ class AggServer:
                 f"overflow the int32 sum ({self._count} accepted so far); "
                 f"anchor the round (RoundSpec.anchor_digest) so "
                 f"coordinates stay ~y/s instead of ~|x|/s")
-        if self._ksum_st is None:
-            self._ksum_st = np.zeros((self.spec.padded,), np.int64)
-        self._ksum_st += k_eff
+        # exact: every partial sum stays under count * max|k| < 2^31
+        self._ksum = ksum
         self._count += m
         self._obs.inc("queued")
         self._obs.inc("accepted")
         if m == 1:
-            self._obs.set_max("max_dist", float(rec.dist_b.max()))
-            self._stats.dist_b = np.maximum(self._stats.dist_b, rec.dist_b)
+            self._obs.set_max("max_dist", float(dist_b.max()))
+            self._stats.dist_b = np.maximum(self._stats.dist_b, dist_b)
         self._accepted.add(h.client_id)
         return self._ack(h.client_id, ack=h.n_chunks)
 
     def _nack_streamed(self, h: wire.FrameHeader,
-                       rec: _StreamFold) -> wire.Response:
+                       dist_b: np.ndarray) -> wire.Response:
         """§5 checksum mismatch on a completed stream: the same escalation
         verdict the batched drain would have produced."""
         self._obs.inc("decode_failures")
@@ -610,7 +655,7 @@ class AggServer:
             y_col = np.asarray(wire.y_buckets_at_attempt(self.spec,
                                                          h.attempt))
             self._stats.fails_b = self._stats.fails_b + \
-                (rec.dist_b > 1.5 * y_col).astype(np.float32)
+                (dist_b > 1.5 * y_col).astype(np.float32)
         nxt = h.attempt + 1
         if h.q >= wire.Q_CAP or nxt >= self.spec.max_attempts:
             self._gave_up.add(h.client_id)
@@ -887,13 +932,7 @@ class AggServer:
                 return np.zeros((self.spec.d,), np.float32), self.stats
             return (np.asarray(rounds.unbucketize(self._anchor_b, self.spec)),
                     self.stats)
-        ksum = self._ksum
-        if self._ksum_st is not None:
-            # merge the streamed commits — exact int64 -> int32, safe under
-            # the same count * max|k| < 2^31 bound as the batched drain
-            ksum = ksum + jnp.asarray(
-                self._ksum_st.reshape(ksum.shape).astype(np.int32))
-        mean_b = _mean_math(ksum, jnp.int32(self._count), self._u,
+        mean_b = _mean_math(self._ksum, jnp.int32(self._count), self._u,
                             self._sides[:, None])
         if self.spec.anchored:
             mean_b = mean_b + self._anchor_b

@@ -65,7 +65,7 @@ import numpy as np
 import repro.obs as _obs
 from repro.agg import rounds
 from repro.agg.api import PublishedRound
-from repro.agg.server import AggServer, _StreamFold, _reject, _retry
+from repro.agg.server import AggServer, _reject, _retry
 from repro.agg.transport import chunks as C
 from repro.agg.transport import frame as wire
 from repro.agg.transport import session as S
@@ -85,6 +85,23 @@ _MAX_PUMP = 64
 # ticks re-sends its full upstream frame sequence (recovers total loss of
 # the combined payload, where no reassembly exists upstream to RESEND)
 _UP_RESEND_TICKS = 2
+
+
+class _StreamFold:
+    """Speculative per-stream fold of a tier's streaming intake.
+
+    One per open ``(client, attempt, payload_crc)`` stream identity: the
+    int16 residuals folded so far (|r| <= q/2 <= 2^15 at the q=2^16 packing
+    cap, so int16 always fits) and the incrementally-accumulated §5
+    coordinate checksum (h(k) is linear in k, so per-range partial sums of
+    ``w_i * k_i`` compose exactly mod 2^32).  Nothing here has touched the
+    tier's sum — dropping the record IS the rollback."""
+    __slots__ = ("r", "check", "coords")
+
+    def __init__(self, padded: int):
+        self.r = np.zeros((padded,), np.int16)
+        self.check = 0          # the uint32 value, carried as a python int
+        self.coords = 0
 
 
 @dataclasses.dataclass
@@ -373,8 +390,7 @@ class TierAggregator:
         key = (h.client_id, h.attempt, h.payload_crc)
         rec = self._folds.get(key)
         if rec is None:
-            rec = self._folds[key] = _StreamFold(self.spec.padded,
-                                                 self.spec.nb)
+            rec = self._folds[key] = _StreamFold(self.spec.padded)
         c0 = word_start * (32 // L.bits_for_q(h.q))
         r = np.asarray(K.lattice_residuals_range(
             jnp.asarray(words), self._k0_j, q=h.q, word_start=word_start))

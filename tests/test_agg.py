@@ -559,6 +559,35 @@ def test_server_overflow_guard_unanchored_large_norm():
     assert stats.accepted == S
     exact = xs.astype(np.float64).mean(0)
     assert float(np.abs(mean - exact).max()) <= 2 * spec.y0
+def test_streamed_commit_overflow_guard_leaves_accumulator_untouched():
+    """The streaming commit applies the int32 guard before it keeps the
+    candidate sum: the frame that completes the offending stream raises
+    OverflowError, and the accumulator and count are those of the streams
+    committed before it."""
+    rng = np.random.RandomState(0)
+    d, bucket, S = 512, 64, 40
+    mu = 2e6 * np.abs(rng.randn(d)).astype(np.float32) + 1e6
+    xs = mu[None] + 0.01 * rng.randn(S, d).astype(np.float32)
+    spec = wire.RoundSpec(round_id=1, d=d,
+                          cfg=QSyncConfig(q=16, bucket=bucket), y0=0.5,
+                          mtu=96, window=2)
+    server = AggServer(spec, mu)
+    assert server._streaming and spec.n_chunks() > 1
+    frames = [f for fs in sim.fleet_frames(spec, xs) for f in fs]
+    for f in frames:
+        ksum, count = np.asarray(server._ksum), server._count
+        try:
+            server.receive(f)
+        except OverflowError as e:
+            assert "anchor the round" in str(e)
+            break
+    else:
+        pytest.fail("the streamed commits never tripped the int32 guard")
+    assert 0 < count < S
+    assert server._count == count
+    assert np.array_equal(np.asarray(server._ksum), ksum)
+
+
 
 
 def test_service_anchor_chain_digests():

@@ -1,4 +1,5 @@
-"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+"""Compile the main-path Pallas kernels, and the streaming drain's jitted
+fold, for a described TPU v5e chip.
 
 Nothing runs: each kernel is lowered at real width (N = 2^20 coordinates)
 and compiled by the TPU compiler for one chip of a described ``v5e:2x2``
@@ -103,3 +104,25 @@ def test_fwht_compiles(one_chip):
     c = _compile(functools.partial(fwht_pallas, interpret=False), one_chip,
                  ((N // d, d), F32))
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("q", [16, 65536])
+def test_stream_fold_compiles_in_place(one_chip, q):
+    """The streaming drain's per-range fold at a 64 KiB range writes into
+    the donated (N,) int16 record in place, and the commit over the whole
+    record compiles beside it."""
+    from repro.agg.server import _commit_math, _fold_range_math
+    nw, bucket = (1 << 16) // 4, 4096
+    n = nw * 32 // L.bits_for_q(q)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fold = _fold_range_math.lower(
+        arg((N,), jnp.int16), arg((nw,), U32), arg((N,), jnp.int32),
+        arg((), jnp.int32), q=q, n=n).compile()
+    assert fold.memory_analysis().alias_size_in_bytes == 2 * N
+    _commit_math.lower(
+        arg((N,), jnp.int16), arg((N // bucket, bucket), jnp.int32),
+        arg((N,), jnp.int32), arg((N,), U32), arg((N,), F32), arg((N,), F32),
+        arg((N,), F32), m=1, bucket=bucket).compile()

@@ -767,6 +767,46 @@ def test_streaming_mid_stream_escalation_resets_fold():
     assert np.array_equal(mean.view(np.uint32), mean_ref.view(np.uint32))
 
 
+def test_streaming_fold_reads_back_once_per_update():
+    """The streaming fold keeps each record on the device: a lossless
+    streamed round makes exactly one blocking readback per accepted update
+    (the commit's verdict), however many ranges each stream folds; the
+    sealed drain makes none."""
+    spec = _spec(d=2048, bucket=256, mtu=300, window=2)
+    base, _, fleets = _fleet(spec, 5)
+    assert len(fleets[0]) > 2
+    for streaming, syncs in ((True, 5), (False, 0)):
+        server = AggServer(spec, base, streaming=streaming)
+        for fs in fleets:
+            for f in fs:
+                server.receive(f)
+        _, stats = server.finalize()
+        assert stats.accepted == 5
+        assert stats.fold_syncs == syncs, streaming
+
+
+def test_streaming_distance_telemetry_matches_sealed_drain():
+    """The streamed commit computes the per-bucket distance telemetry by
+    the sealed drain's own expression: ``dist_b`` and ``max_dist`` agree
+    with the batched decode's for the same payloads, to float32
+    rounding."""
+    spec = _spec(d=2048, bucket=256, mtu=300, window=2)
+    base, _, fleets = _fleet(spec, 4, spread=0.2)
+    out = {}
+    for streaming in (True, False):
+        server = AggServer(spec, base, streaming=streaming)
+        for fs in fleets:
+            for f in fs:
+                server.receive(f)
+        _, out[streaming] = server.finalize()
+    st, se = out[True], out[False]
+    assert st.accepted == se.accepted == 4
+    assert se.max_dist > 0
+    np.testing.assert_allclose(st.dist_b, se.dist_b, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(st.max_dist, se.max_dist, rtol=1e-6, atol=0)
+    assert not st.fails_b.any() and not se.fails_b.any()
+
+
 def test_send_window_paces_and_counts_stalls():
     """SendWindow unit behavior: at most ``window`` in flight, cumulative
     acks release more, RESENDs below the sent prefix are the lost set,
