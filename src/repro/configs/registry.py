@@ -12,6 +12,7 @@ ARCHS = {
     "mamba2-1.3b": "mamba2_13b",
     "recurrentgemma-9b": "recurrentgemma_9b",
     "internvl2-1b": "internvl2_1b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

@@ -22,6 +22,7 @@ from repro.kernels.lattice_encode import lattice_encode_pallas
 from repro.kernels.lattice_decode import (lattice_decode_pallas,
                                           lattice_decode_batched_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels import grouped_matmul as _gmm
 
 # Kernel-dispatch telemetry: how many decode launches each wrapper has issued
 # (counted at trace time — one entry per kernel launch in the compiled
@@ -231,3 +232,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return _ref.flash_attention_ref(q, k, v, causal=causal)
     return flash_attention_pallas(q, k, v, causal=causal, bq=bq, bk=bk,
                                   interpret=_interpret())
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array,
+                   out_dtype=jnp.float32) -> jax.Array:
+    """Rows sorted by group (m, k) x group weights (G, k, n) -> (m, n)
+    ``out_dtype``, differentiable (``kernels/grouped_matmul.py``).
+    ``sizes`` (G or G + 1,) int32; the rows of a last group past G come out
+    zero."""
+    return _gmm.grouped_matmul(lhs, rhs, sizes, jnp.dtype(out_dtype),
+                               _interpret())

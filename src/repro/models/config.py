@@ -20,9 +20,22 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0          # experts the router scores
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # 0: dropless (grouped matmuls)
+    moe_d_ff: int = 0           # expert width (0: d_ff)
+    n_shared_experts: int = 0   # SwiGLU of n_shared * moe_d_ff every token takes
+    first_dense_layers: int = 0     # leading dense layers before the MoE scan
+    router: str = "softmax"     # softmax | sigmoid_bias (selection bias)
+    routed_scale: float = 1.0   # factor on the normalised top-k weights
+    norm_topk: bool = True
+    aux_coef: float = 0.01      # load-balance loss coefficient
+    experts_held: int = 0       # the chip's share: experts computed here
+    expert_offset: int = 0      # (0 held: all of them)
+    # multi-head latent attention (0: none); head_dim is the no-rope width
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -42,6 +55,15 @@ class ModelConfig:
     norm_eps: float = 1e-5
     emb_scale: float = 1.0
     tie_embeddings: bool = False
+
+    @property
+    def held(self) -> int:
+        """Experts whose part of each MoE layer this chip computes."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def q_per_kv(self) -> int:

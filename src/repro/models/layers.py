@@ -167,6 +167,59 @@ def attention(xg: Array, w: dict, cfg: ModelConfig, ctx: ShardCtx, *,
     return out
 
 
+def mla_attention(xg: Array, w: dict, cfg: ModelConfig, *,
+                  positions: Array) -> Array:
+    """Multi-head latent attention (DeepSeek-V2/V3), the expanded form, over
+    gathered tokens; every head on this chip (tensor parallelism is not
+    supported).
+
+    q = x Wq, per head [nope (head_dim) | rope (qk_rope_dim)];
+    [c_kv | k_pe] = x Wkv_a; c_kv = RMSNorm(c_kv); [k_nope | v] = c_kv Wkv_b
+    per head; rotary positions on q's rope part and on the one k_pe every
+    head shares; softmax scale 1/sqrt(head_dim + qk_rope_dim).  Over
+    ``ATTN_CHUNK`` tokens the queries go in chunks whose body is
+    rematerialised, so no (B, H, S, S) tensor is kept for the backward.
+
+    xg: (B, S, D); w: {"wq": (D, H*(hd+r)), "wkv_a": (D, R+r), "kv_norm":
+    (R,), "wkv_b": (R, H*(hd+vd)), "wo": (H*vd, D)} -> (B, S, D)."""
+    B, S, _ = xg.shape
+    H, hd, r = cfg.n_heads, cfg.head_dim, cfg.qk_rope_dim
+    vd, R = cfg.v_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla"):
+        q = (xg @ w["wq"]).reshape(B, S, H, hd + r)
+        kv_a = xg @ w["wkv_a"]
+        c_kv = rms_norm(kv_a[..., :R], w["kv_norm"], cfg.norm_eps)
+        kv = (c_kv @ w["wkv_b"]).reshape(B, S, H, hd + vd)
+        cos, sin = rope_angles(positions, r, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :hd], apply_rope(q[..., hd:], cos, sin)],
+                            -1)
+        k_pe = apply_rope(kv_a[..., None, R:], cos, sin)
+        k = jnp.concatenate([kv[..., :hd],
+                             jnp.broadcast_to(k_pe, (B, S, H, r))], -1)
+        v = kv[..., hd:]
+        scale = 1.0 / np.sqrt(hd + r)
+        if S <= ATTN_CHUNK:
+            mask = positions[:, None] >= positions[None, :]
+            out = _softmax_attend(q, k, v, mask, scale)
+        else:
+            C = ATTN_CHUNK
+            pad = (-S) % C
+            qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            pp = jnp.pad(positions, (0, pad), constant_values=-1)
+            n = qp.shape[1] // C
+            qc = qp.reshape(B, n, C, H, hd + r).swapaxes(0, 1)
+
+            @jax.checkpoint
+            def body(carry, inp):
+                qi, pi = inp
+                mask = pi[:, None] >= positions[None, :]
+                return carry, _softmax_attend(qi, k, v, mask, scale)
+
+            _, oc = jax.lax.scan(body, None, (qc, pp.reshape(n, C)))
+            out = oc.swapaxes(0, 1).reshape(B, n * C, H, vd)[:, :S]
+        return out.reshape(B, S, H * vd) @ w["wo"]
+
+
 def mlp(xg: Array, w: dict, cfg: ModelConfig) -> Array:
     """Gathered-token MLP; returns partial output (psum over tp by caller).
 

@@ -89,6 +89,14 @@ def cache_struct(cfg: ModelConfig, ctx: ShardCtx, batch_local: int,
             d[f"tail{t}_lru"] = (B, c_loc)
             d[f"tail{t}_conv"] = (B, cfg.conv_width - 1, c_loc)
         return d
+    if cfg.kv_lora_rank:
+        # latent attention caches the normed latent and the shared rope key
+        R, r = cfg.kv_lora_rank, cfg.qk_rope_dim
+        d = {"ckv": (L, B, s_max, R), "kpe": (L, B, s_max, r)}
+        for i in range(cfg.first_dense_layers):
+            d[f"dense{i}_ckv"] = (B, s_max, R)
+            d[f"dense{i}_kpe"] = (B, s_max, r)
+        return d
     g1, g2 = groups_of(cfg, ctx)
     kv_loc = cfg.n_kv // g1
     s_loc = -(-s_max // g2)
@@ -261,6 +269,50 @@ def decode_attention(x: Array, wts: dict, ck: Array, cv: Array, pos: Array,
     return out, ck, cv
 
 
+def mla_decode(x: Array, wts: dict, ckv: Array, kpe: Array, pos: Array,
+               cfg: ModelConfig):
+    """Latent attention of one token per sequence against the latent cache
+    (the expanded form, as layers.mla_attention; tp=1).  x: (B, D); ckv:
+    (B, S, R); kpe: (B, S, r).  Returns (out (B, D), ckv, kpe)."""
+    B, _ = x.shape
+    H, hd, r = cfg.n_heads, cfg.head_dim, cfg.qk_rope_dim
+    vd, R = cfg.v_head_dim, cfg.kv_lora_rank
+    q = (x @ wts["wq"]).reshape(B, 1, H, hd + r)
+    kv_a = x @ wts["wkv_a"]
+    c = LY.rms_norm(kv_a[:, :R], wts["kv_norm"], cfg.norm_eps)
+    cos, sin = LY.rope_angles(pos[None], r, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :hd], LY.apply_rope(q[..., hd:], cos, sin)],
+                        -1)[:, 0]
+    k_pe = LY.apply_rope(kv_a[:, None, None, R:], cos, sin)[:, 0, 0]
+    ckv = jax.lax.dynamic_update_slice(ckv, c[:, None].astype(ckv.dtype),
+                                       (0, pos, 0))
+    kpe = jax.lax.dynamic_update_slice(kpe, k_pe[:, None].astype(kpe.dtype),
+                                       (0, pos, 0))
+    S = ckv.shape[1]
+    kv = (ckv @ wts["wkv_b"]).reshape(B, S, H, hd + vd)
+    k = jnp.concatenate([kv[..., :hd], jnp.broadcast_to(
+        kpe[:, :, None], (B, S, H, r))], -1)
+    logits = jnp.einsum("bhd,bshd->bhs", q, k,
+                        preferred_element_type=jnp.float32) / np.sqrt(hd + r)
+    logits = jnp.where((jnp.arange(S) <= pos)[None, None], logits, -1e30)
+    p = jax.nn.softmax(logits, axis=-1).astype(kv.dtype)
+    o = jnp.einsum("bhs,bshd->bhd", p, kv[..., hd:])
+    return o.reshape(B, H * vd) @ wts["wo"], ckv, kpe
+
+
+def _mla_decode_layer(x: Array, wts: dict, ckv: Array, kpe: Array,
+                      pos: Array, cfg: ModelConfig):
+    a = LY.rms_norm(x, wts["ln1"], cfg.norm_eps)
+    att, ckv, kpe = mla_decode(a, wts, ckv, kpe, pos, cfg)
+    x = x + att
+    m = LY.rms_norm(x, wts["ln2"], cfg.norm_eps)
+    if "router" in wts:
+        out, _, _ = MOE.share_moe(m, wts, cfg, None, m.shape[0])
+    else:
+        out = LY.mlp(m, wts, cfg)
+    return x + out, ckv, kpe
+
+
 def window_decode_attention(x: Array, wts: dict, ck: Array, cv: Array,
                             pos: Array, cfg: ModelConfig, ctx: ShardCtx):
     """Ring-buffer window cache, replicated across tp; heads sharded.
@@ -403,6 +455,16 @@ def make_serve_step(cfg: ModelConfig, ctx: ShardCtx, kv_quant: bool = False):
         emb = gather_param(params["top"]["embed"], metas["top"]["embed"], ctx,
                            zero_y(), _leaf_key(kt, "embed"), tz, gathers)
         x = LY.vp_embed(tokens[:, 0], emb, ctx) * cfg.emb_scale   # (B, D)
+        lead = {}
+        for i in range(cfg.first_dense_layers):
+            p = f"dense{i}_"
+            kl = jax.random.fold_in(key, 20_000 + i)
+            sw = {k[len(p):]: gather_param(
+                params["top"][k], metas["top"][k], ctx, zero_y(),
+                _leaf_key(kl, k), tz, gathers)
+                for k in metas["top"] if k.startswith(p)}
+            x, lead[f"{p}ckv"], lead[f"{p}kpe"] = _mla_decode_layer(
+                x, sw, cache[f"{p}ckv"], cache[f"{p}kpe"], pos, cfg)
 
         def body(carry, xs):
             xc = carry
@@ -422,6 +484,9 @@ def make_serve_step(cfg: ModelConfig, ctx: ShardCtx, kv_quant: bool = False):
                       "conv_bc": ns["conv_bc"]}
             elif cfg.family == "hybrid":
                 xc, nc = _hybrid_decode_unit(xc, wts, lc, pos, cfg, ctx)
+            elif cfg.kv_lora_rank:
+                xc, nc["ckv"], nc["kpe"] = _mla_decode_layer(
+                    xc, wts, lc["ckv"], lc["kpe"], pos, cfg)
             else:
                 a = LY.rms_norm(xc, wts["ln1"], cfg.norm_eps)
                 if kv_quant:
@@ -448,10 +513,12 @@ def make_serve_step(cfg: ModelConfig, ctx: ShardCtx, kv_quant: bool = False):
             out, nc = body(carry, (lp, lc, xs[2]))
             return out, nc
 
-        cache_scan = {k: v for k, v in cache.items() if not k.startswith("tail")}
+        cache_scan = {k: v for k, v in cache.items()
+                      if not k.startswith(("tail", "dense"))}
         x, new_cache = jax.lax.scan(
             sbody, x, (params["layers"], cache_scan,
                        jnp.arange(L, dtype=jnp.int32)))
+        new_cache.update(lead)
 
         # hybrid unscanned tail recurrent layers
         if cfg.family == "hybrid" and cfg.n_layers % 3:
@@ -527,7 +594,11 @@ def make_prefill(cfg: ModelConfig, ctx: ShardCtx):
 
     Uses the training forward (head-sharded attention) and re-shards the
     computed K/V into the decode layout (kv-group x seq-chunk local slices).
+    Latent attention has no prefill here: its cache fills token by token.
     """
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(f"{cfg.arch}: no prefill for latent "
+                                  f"attention")
     metas = all_metas(cfg, ctx)
     gathers = make_gathers(ctx)
     L = n_scan_steps(cfg)

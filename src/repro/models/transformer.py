@@ -83,6 +83,37 @@ def _moe_metas(cfg: ModelConfig, ctx: ShardCtx) -> dict[str, LeafMeta]:
     return m
 
 
+def _mla_metas(cfg: ModelConfig, prefix: str = "") -> dict[str, LeafMeta]:
+    """Latent attention (layers.mla_attention); every head on this chip."""
+    D, H, hd, r = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.qk_rope_dim
+    R, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    return {
+        f"{prefix}wq": LeafMeta((D, H * (hd + r))),
+        f"{prefix}wkv_a": LeafMeta((D, R + r)),
+        f"{prefix}kv_norm": LeafMeta((R,), init="ones"),
+        f"{prefix}wkv_b": LeafMeta((R, H * (hd + vd))),
+        f"{prefix}wo": LeafMeta((H * vd, D)),
+    }
+
+
+def _share_metas(cfg: ModelConfig) -> dict[str, LeafMeta]:
+    """The router over every expert, the held experts, the shared experts
+    (moe.share_moe)."""
+    D, F, E = cfg.d_model, cfg.expert_width, cfg.held
+    m = {
+        "router": LeafMeta((D, cfg.n_experts)),
+        "w1": LeafMeta((E, D, F)),
+        "w3": LeafMeta((E, D, F)),
+        "w2": LeafMeta((E, F, D)),
+    }
+    if cfg.n_shared_experts:
+        Fs = cfg.n_shared_experts * F
+        m.update({"shared_wg": LeafMeta((D, Fs)),
+                  "shared_wu": LeafMeta((D, Fs)),
+                  "shared_wd": LeafMeta((Fs, D))})
+    return m
+
+
 def _ssm_metas(cfg: ModelConfig, ctx: ShardCtx) -> dict[str, LeafMeta]:
     D = cfg.d_model
     inner = cfg.ssm_expand * D
@@ -130,6 +161,9 @@ def block_metas(cfg: ModelConfig, ctx: ShardCtx) -> dict[str, LeafMeta]:
     if cfg.family in ("dense", "vlm"):
         return {"ln1": ln(), "ln2": ln(),
                 **_attn_metas(cfg, ctx), **_mlp_metas(cfg, ctx)}
+    if cfg.family == "moe" and cfg.kv_lora_rank:
+        return {"ln1": ln(), "ln2": ln(),
+                **_mla_metas(cfg), **_share_metas(cfg)}
     if cfg.family == "moe":
         return {"ln1": ln(), "ln2": ln(),
                 **_attn_metas(cfg, ctx), **_moe_metas(cfg, ctx)}
@@ -171,11 +205,21 @@ def top_metas(cfg: ModelConfig, ctx: ShardCtx) -> dict[str, LeafMeta]:
                 m[k] = dataclasses.replace(v, scanned=False)
             for k, v in _mlp_metas(cfg, ctx, p).items():
                 m[k] = dataclasses.replace(v, scanned=False)
+    for i in range(cfg.first_dense_layers):
+        # leading dense layers (latent attention + SwiGLU), unscanned
+        p = f"dense{i}_"
+        lead = {f"{p}ln1": LeafMeta((D,), init="ones"),
+                f"{p}ln2": LeafMeta((D,), init="ones"),
+                **_mla_metas(cfg, p), **_mlp_metas(cfg, ctx, p)}
+        m.update({k: dataclasses.replace(v, scanned=False)
+                  for k, v in lead.items()})
     return m
 
 
 def n_scan_steps(cfg: ModelConfig) -> int:
-    return cfg.n_layers // 3 if cfg.family == "hybrid" else cfg.n_layers
+    if cfg.family == "hybrid":
+        return cfg.n_layers // 3
+    return cfg.n_layers - cfg.first_dense_layers
 
 
 def all_metas(cfg: ModelConfig, ctx: ShardCtx) -> dict[str, dict[str, LeafMeta]]:
@@ -305,6 +349,20 @@ def dense_block(x: Array, wts: dict, cfg: ModelConfig, ctx: ShardCtx,
     return x, aux
 
 
+def mla_block(x: Array, wts: dict, cfg: ModelConfig, positions: Array,
+              bias: Optional[Array] = None):
+    """Latent attention, then a SwiGLU (a leading dense layer) or the
+    chip's share of the MoE layer.  Returns (x, aux, loads or None)."""
+    B, S, D = x.shape
+    a_in = LY.rms_norm(x, wts["ln1"], cfg.norm_eps)
+    x = x + LY.mla_attention(a_in, wts, cfg, positions=positions)
+    m_in = LY.rms_norm(x, wts["ln2"], cfg.norm_eps)
+    if "router" not in wts:
+        return x + LY.mlp(m_in, wts, cfg), jnp.zeros((), jnp.float32), None
+    out, aux, loads = MOE.share_moe(m_in.reshape(B * S, D), wts, cfg, bias, B)
+    return x + out.reshape(B, S, D), aux, loads
+
+
 def ssm_block(x: Array, wts: dict, cfg: ModelConfig, ctx: ShardCtx) -> Array:
     a_in = LY.rms_norm(x, wts["ln1"], cfg.norm_eps)
     xg = LY.sp_enter(a_in, ctx)
@@ -411,7 +469,13 @@ def make_loss_fn(cfg: ModelConfig, ctx: ShardCtx) -> Callable:
     split = make_split_gathers(ctx) if ctx.prefetch else None
     L = n_scan_steps(cfg)
 
-    def loss_fn(params, tele, batch, key, y):
+    if cfg.kv_lora_rank and (ctx.tp > 1 or ctx.prefetch):
+        raise ValueError(f"{cfg.arch}: latent attention and the expert "
+                         f"share run with tp=1 and the serial layer scan")
+
+    def loss_fn(params, tele, batch, key, y, bias=None):
+        """``bias``: (layers, n_experts) selection bias of a sigmoid_bias
+        router (zeros when not given)."""
         tokens = batch["tokens"]
         B, S = tokens.shape
         kt = jax.random.fold_in(key, 0)
@@ -429,7 +493,21 @@ def make_loss_fn(cfg: ModelConfig, ctx: ShardCtx) -> Callable:
             s_loc = S_full // ctx.tp
             x = jax.lax.dynamic_slice_in_dim(x, tp_index(ctx) * s_loc, s_loc, 1)
 
-        def apply_block(xcur, wts):
+        if cfg.router == "sigmoid_bias" and bias is None:
+            bias = jnp.zeros((L, cfg.n_experts), jnp.float32)
+
+        for i in range(cfg.first_dense_layers):
+            p = f"dense{i}_"
+            kl = jax.random.fold_in(key, 20_000 + i)
+            sw = {k[len(p):]: gather_param(
+                params["top"][k], metas["top"][k], ctx, y["top"][k],
+                _leaf_key(kl, k), tele["top"][k], gathers)
+                for k in metas["top"] if k.startswith(p)}
+            x, _, _ = mla_block(x, sw, cfg, positions)
+
+        def apply_block(xcur, wts, *bias_l):
+            if cfg.kv_lora_rank:
+                return mla_block(xcur, wts, cfg, positions, *bias_l)
             if cfg.family == "ssm":
                 return ssm_block(xcur, wts, cfg, ctx), jnp.zeros((), jnp.float32)
             if cfg.family == "hybrid":
@@ -446,20 +524,22 @@ def make_loss_fn(cfg: ModelConfig, ctx: ShardCtx) -> Callable:
         else:
             def body(carry, xs):
                 xcur, auxsum = carry
-                lp, ly, lt, idx = xs
+                lp, ly, lt, idx = xs[:4]
                 kl = jax.random.fold_in(key, idx + 1)
                 wts = _gather_tree(lp, metas["layers"], ctx, ly, kl, lt,
                                    gathers)
-                xnew, aux = apply_block(xcur, wts)
-                return (xnew, auxsum + aux), None
+                xnew, aux, *loads = apply_block(xcur, wts, *xs[4:])
+                return (xnew, auxsum + aux), (loads[0] if loads else None)
 
             body_fn = jax.checkpoint(body) if ctx.remat else body
             xs = (params["layers"],
                   y["layers"],
                   tele["layers"],
                   jnp.arange(L, dtype=jnp.int32))
-            (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                                       xs)
+            if bias is not None:
+                xs = xs + (bias,)
+            (x, aux), loads = jax.lax.scan(
+                body_fn, (x, jnp.zeros((), jnp.float32)), xs)
 
         # hybrid tail layers (unscanned)
         if cfg.family == "hybrid" and cfg.n_layers % 3:
@@ -511,8 +591,10 @@ def make_loss_fn(cfg: ModelConfig, ctx: ShardCtx) -> Callable:
                                None if mask is None else mask.reshape(-1))
         loss = nll_sum / jnp.maximum(cnt, 1.0)
 
-        loss = loss + 0.01 * aux
+        loss = loss + cfg.aux_coef * aux
         metrics = {"loss": loss, "aux": aux}
+        if cfg.kv_lora_rank:
+            metrics["loads"] = loads      # (layers, n_experts) rows routed
         # shard_map autodiff computes d(sum over devices of the returned
         # scalar)/dw (transpose(psum) = psum); the loss here is replicated
         # over tp, so scale by 1/tp so per-device grads are exact.

@@ -64,6 +64,10 @@ class TrainConfig:
                                    # rotated-space bound (sharding.leaf_y0)
     y_decay: float = 0.99          # relax y toward measured distance
     y_escalate: float = 2.0        # on detected decode failure
+    bias_rate: float = 1e-3        # a sigmoid_bias router's selection-bias
+                                   # step (DeepSeek-V3's gamma)
+    donate: bool = False           # the step consumes its input state (one
+                                   # copy of the state resident, not two)
 
 
 def _y_update(y, tele: Array, tc: TrainConfig):
@@ -99,9 +103,37 @@ def _y_update(y, tele: Array, tc: TrainConfig):
     return jnp.where(fails > 0, y * tc.y_escalate, relaxed)
 
 
+def update_bias(bias: Array, loads: Array, rate: float) -> Array:
+    """Aux-loss-free balancing (DeepSeek-V3, arXiv:2412.19437 §2.1.2): each
+    layer's selection bias rises by ``rate`` for the experts under the
+    layer's mean load and falls by it for those over.  bias (L, E) f32,
+    loads (L, E) rows routed to each expert in the step."""
+    lf = loads.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(lf, -1, keepdims=True) - lf)
+
+
+def record_moe_loads(loads, cfg: ModelConfig) -> tuple[int, int]:
+    """Count a step's (or several steps' summed) loads (L, E) into the
+    ``moe.rows_held`` and ``moe.rows_routed`` counters of ``repro.obs``:
+    the rows routed to this chip's held experts and to all experts.
+    Reads ``loads`` back; returns (held, routed)."""
+    import repro.obs as obs
+    a = np.asarray(loads).astype(np.int64)
+    lo = cfg.expert_offset
+    held, routed = int(a[:, lo:lo + cfg.held].sum()), int(a.sum())
+    obs.counter("moe.rows_held").inc(held)
+    obs.counter("moe.rows_routed").inc(routed)
+    return held, routed
+
+
 def make_train_step(cfg: ModelConfig, ctx: ShardCtx, mesh, opt_cfg: O.OptConfig,
                     tc: TrainConfig):
-    """Returns jitted step(state, batch) -> (state, metrics)."""
+    """Returns jitted step(state, batch) -> (state, metrics).
+
+    A ``sigmoid_bias`` router adds the state leaf ``moe_bias`` (L, E) f32,
+    outside the gradient and the sync, updated after each step from the
+    step's loads (summed over ``data``), and the metric ``loads`` (L, E)
+    int32."""
     metas = T.all_metas(cfg, ctx)
     loss_fn = T.make_loss_fn(cfg, ctx)
     L = T.n_scan_steps(cfg)
@@ -129,6 +161,12 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, mesh, opt_cfg: O.OptConfig,
               "top": {k: _y_leaf_spec(m) for k, m in metas["top"].items()}}
     state_spec = {"params": pspec, "opt": opt_spec, "y": y_spec, "step": P(),
                   "key": P()}
+    biased = cfg.router == "sigmoid_bias"
+    if biased:
+        if tc.microbatch > 1:
+            raise ValueError("a sigmoid_bias router's loads are not "
+                             "accumulated over microbatches")
+        state_spec["moe_bias"] = P()
 
     def batch_spec(batch):
         return {k: bspec_leaf for k in batch}
@@ -139,10 +177,12 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, mesh, opt_cfg: O.OptConfig,
         kstep = jax.random.fold_in(key, step)
         tele0 = T.tele_zeros(cfg, ctx)
 
+        bias = state.get("moe_bias")
+
         def lg(batch_mb):
             (l, metrics), (gp, gt) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(
-                    params, tele0, batch_mb, kstep, y)
+                    params, tele0, batch_mb, kstep, y, bias)
             return metrics, gp, gt
 
         if tc.microbatch > 1:
@@ -198,6 +238,12 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, mesh, opt_cfg: O.OptConfig,
         new_state = {"params": params2, "opt": opt2, "y": y2,
                      "step": step + 1, "key": key}
         out_metrics = {"loss": loss_rep, "gnorm": gnorm, "fails": fails}
+        if biased:
+            loads = metrics["loads"]
+            for ax in ctx.dp_axes:
+                loads = jax.lax.psum(loads, ax)
+            new_state["moe_bias"] = update_bias(bias, loads, tc.bias_rate)
+            out_metrics["loads"] = loads
         return new_state, out_metrics
 
     def step_fn(state, batch):
@@ -207,19 +253,27 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, mesh, opt_cfg: O.OptConfig,
                           check_vma=False)
         return f(state, batch)
 
-    return jax.jit(step_fn), state_spec, pspec
+    return (jax.jit(step_fn, donate_argnums=(0,) if tc.donate else ()),
+            state_spec, pspec)
 
 
 def init_state(cfg: ModelConfig, ctx: ShardCtx, opt_cfg: O.OptConfig,
                tc: TrainConfig, key: Array) -> dict:
     params = T.init_params(cfg, ctx, key)
-    return {
+    state = {
         "params": params,
         "opt": O.init_opt_state(params, opt_cfg),
         "y": T.y_init(cfg, ctx, tc.y0),
         "step": jnp.zeros((), jnp.int32),
         "key": key,
     }
+    if cfg.router == "sigmoid_bias":
+        state["moe_bias"] = moe_bias_init(cfg)
+    return state
+
+
+def moe_bias_init(cfg: ModelConfig) -> Array:
+    return jnp.zeros((T.n_scan_steps(cfg), cfg.n_experts), jnp.float32)
 
 
 class Trainer:
@@ -300,8 +354,10 @@ class Trainer:
         opt_logical = {k: C.params_to_logical(v, self.metas, self.ctx)
                        for k, v in state["opt"].items()}
         y_np = jax.tree.map(np.asarray, state["y"])
-        C.save(self.tc.ckpt_dir, step,
-               {"params": logical, "opt": opt_logical, "y": y_np},
+        tree = {"params": logical, "opt": opt_logical, "y": y_np}
+        if "moe_bias" in state:
+            tree["moe_bias"] = np.asarray(state["moe_bias"])
+        C.save(self.tc.ckpt_dir, step, tree,
                {"arch": self.cfg.arch}, keep=self.tc.keep)
 
     def restore(self) -> Optional[dict]:
@@ -329,6 +385,8 @@ class Trainer:
                         zip(jax.tree.leaves(restored_y),
                             jax.tree.leaves(state["y"])))):
             state["y"] = restored_y
+        if "moe_bias" in tree and "moe_bias" in state:
+            state["moe_bias"] = jnp.asarray(tree["moe_bias"])
         state["step"] = jnp.asarray(step, jnp.int32)
         return state
 
@@ -338,6 +396,7 @@ class Trainer:
                 self.cfg, self.ctx, self.opt_cfg, self.tc,
                 jax.random.PRNGKey(0))
         restarts = 0
+        loads_acc = None       # summed on the device, read at log steps
         while int(state["step"]) < self.tc.steps:
             step = int(state["step"])
             try:
@@ -346,7 +405,14 @@ class Trainer:
                 batch = self._batch(step)
                 t0 = time.perf_counter()
                 state, metrics = self.step_fn(state, batch)
+                if "loads" in metrics:
+                    loads = metrics.pop("loads")
+                    loads_acc = loads if loads_acc is None else \
+                        loads_acc + loads
                 if step % self.tc.log_every == 0:
+                    if loads_acc is not None:
+                        record_moe_loads(loads_acc, self.cfg)
+                        loads_acc = None
                     m = {k: float(v) for k, v in metrics.items()}
                     m["step"] = step
                     m["dt"] = time.perf_counter() - t0
@@ -369,5 +435,7 @@ class Trainer:
                                        self.tc, jax.random.PRNGKey(0))
                 else:
                     state = restored
+        if loads_acc is not None:
+            record_moe_loads(loads_acc, self.cfg)
         self.save(state)
         return state

@@ -126,3 +126,24 @@ def test_stream_fold_compiles_in_place(one_chip, q):
         arg((N,), jnp.int16), arg((N // bucket, bucket), jnp.int32),
         arg((N,), jnp.int32), arg((N,), U32), arg((N,), F32), arg((N,), F32),
         arg((N,), F32), m=1, bucket=bucket).compile()
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_expert_grouped_matmul_compiles(one_chip, k, n):
+    """The held experts' grouped products at Moonlight's widths (D 2048 ->
+    F 1408 and back) over 2 x 8192 tokens' top-6 rows, 8 held experts and
+    the absent group, forward and both backward products: ragged group
+    sizes are runtime values, so one compile covers every routing."""
+    from repro.kernels.grouped_matmul import expert_gmm, expert_tgmm
+    m, bf16 = 2 * 8192 * 6, jnp.bfloat16
+    sizes = ((9,), jnp.int32)
+    c = _compile(functools.partial(expert_gmm, interpret=False), one_chip,
+                 ((m, k), bf16), ((8, k, n), bf16), sizes)
+    assert "tpu_custom_call" in c.as_text()
+    c = _compile(functools.partial(expert_gmm, transpose_rhs=True,
+                                   interpret=False), one_chip,
+                 ((m, n), bf16), ((8, k, n), bf16), sizes)
+    assert "tpu_custom_call" in c.as_text()
+    c = _compile(functools.partial(expert_tgmm, groups=8, interpret=False),
+                 one_chip, ((k, m), bf16), ((m, n), bf16), sizes)
+    assert "tpu_custom_call" in c.as_text()
