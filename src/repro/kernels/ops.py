@@ -8,11 +8,12 @@ cover (non-power-of-two FWHT dims, q not a power of two, tiny inputs).
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as _splash
 
 import repro.obs as _obs
 from repro.core import lattice as L
@@ -87,6 +88,11 @@ def reset_dispatch_counts() -> None:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def on_tpu() -> bool:
+    """The kernels compile to Mosaic (not interpret mode) here."""
+    return not _interpret()
 
 
 def _pow2(n: int) -> bool:
@@ -232,6 +238,41 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return _ref.flash_attention_ref(q, k, v, causal=causal)
     return flash_attention_pallas(q, k, v, causal=causal, bq=bq, bk=bk,
                                   interpret=_interpret())
+
+
+@lru_cache(maxsize=None)
+def _causal_kernel(heads: int, seq: int, interpret: bool):
+    """The splash attention kernel (forward, dq and dkv) for ``heads``
+    causal heads over ``seq`` tokens, in blocks of the largest power of two
+    <= 512 that divides ``seq``."""
+    b = 512
+    while seq % b:
+        b //= 2
+    blocks = _splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    mask = _splash.MultiHeadMask([_splash.CausalMask((seq, seq))] * heads)
+    return _splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                   q_seq_shards=1, interpret=interpret)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     scale: float) -> jax.Array:
+    """Causal softmax attention through the installed Pallas splash kernel,
+    differentiable: an online softmax over key blocks that skips the blocks
+    above the diagonal and keeps only the log-sum-exp for the backward, so
+    no (S, S) score row is formed.  q, k: (B, S, H, dk); v: (B, S, H, dv),
+    dv may differ from dk; S a multiple of 128 -> (B, S, H, dv) in q's
+    dtype.  ``scale`` is folded into q (in f32, then rounded to q's dtype).
+    """
+    _, S, H, _ = q.shape
+    if S % 128:
+        raise ValueError(f"sequence {S} is not a multiple of 128")
+    kernel = _causal_kernel(H, S, _interpret())
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    out = jax.vmap(kernel)(qs.swapaxes(1, 2), k.swapaxes(1, 2),
+                           v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array,

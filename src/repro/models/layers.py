@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import repro.obs as _obs
+from repro.kernels import ops
 from repro.models.config import ModelConfig
 from repro.models.sharding import (ShardCtx, psum_tp, all_gather_tp,
                                    reduce_scatter_tp, tp_index)
@@ -29,6 +31,11 @@ from repro.models.sharding import (ShardCtx, psum_tp, all_gather_tp,
 Array = jax.Array
 
 ATTN_CHUNK = 512          # query-chunk length for memory-bounded attention
+
+# Which path each attention call of a traced program took (counted at trace
+# time, one count per call): the fused kernel or materialized score rows.
+_ATTN_DISPATCH = {p: _obs.registry().counter("attention_dispatch", path=p)
+                  for p in ("fused", "materialized")}
 
 
 def rms_norm(x: Array, scale: Array, eps: float = 1e-5) -> Array:
@@ -99,6 +106,17 @@ def _softmax_attend(q: Array, k: Array, v: Array, mask: Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _fused(S: int, causal: bool, window: int) -> bool:
+    """Whether self-attention over S tokens at positions 0..S-1 goes through
+    the fused kernel (``ops.causal_attention``): on the TPU, causal with no
+    window, over more than one query chunk, S a multiple of 128.  Else the
+    scores are materialized, in query chunks over ``ATTN_CHUNK``."""
+    fused = (ops.on_tpu() and causal and not window and S > ATTN_CHUNK
+             and S % 128 == 0)
+    _ATTN_DISPATCH["fused" if fused else "materialized"].inc()
+    return fused
+
+
 def attention(xg: Array, w: dict, cfg: ModelConfig, ctx: ShardCtx, *,
               positions: Array, causal: bool = True, window: int = 0,
               kv_out: bool = False):
@@ -130,7 +148,9 @@ def attention(xg: Array, w: dict, cfg: ModelConfig, ctx: ShardCtx, *,
     v_h = jnp.take(v, kv_idx, axis=2)
     scale = 1.0 / np.sqrt(hd)
 
-    if S <= ATTN_CHUNK:
+    if _fused(S, causal, window):
+        out = ops.causal_attention(q, k_h, v_h, scale=scale)
+    elif S <= ATTN_CHUNK:
         qpos = positions
         kpos = positions
         mask = jnp.ones((S, S), bool)
@@ -198,7 +218,9 @@ def mla_attention(xg: Array, w: dict, cfg: ModelConfig, *,
                              jnp.broadcast_to(k_pe, (B, S, H, r))], -1)
         v = kv[..., hd:]
         scale = 1.0 / np.sqrt(hd + r)
-        if S <= ATTN_CHUNK:
+        if _fused(S, True, 0):
+            out = ops.causal_attention(q, k, v, scale=scale)
+        elif S <= ATTN_CHUNK:
             mask = positions[:, None] >= positions[None, :]
             out = _softmax_attend(q, k, v, mask, scale)
         else:

@@ -147,3 +147,130 @@ def test_expert_grouped_matmul_compiles(one_chip, k, n):
     c = _compile(functools.partial(expert_tgmm, groups=8, interpret=False),
                  one_chip, ((k, m), bf16), ((m, n), bf16), sizes)
     assert "tpu_custom_call" in c.as_text()
+
+
+# ------------------------------------------------------------ attention
+
+def _on_chip(monkeypatch):
+    """Steer the program's kernels to Mosaic, as on the chip: the backend
+    here is the CPU, so ``ops`` would pick interpret mode and the models
+    the materialized attention."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _attention_layer(name, one_chip):
+    """(loss of the layer's output, its argument shapes) at a training
+    cell's shape: moonlight.ep8-8k's latent attention (2 x 8192 tokens,
+    16 heads, q/k 192, v 128) or granite.dp1's GQA (5 x 2048 tokens, 16
+    query heads on 8 KV of 64)."""
+    from repro.configs import registry
+    from repro.models import layers as LY
+    from repro.models.sharding import ShardCtx
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if name == "mla_attention":
+        cfg = registry.config("moonlight-16b-a3b")
+        B, S, D, H = 2, 8192, cfg.d_model, cfg.n_heads
+        hd, r, vd = cfg.head_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        R = cfg.kv_lora_rank
+        w = {"wq": arg((D, H * (hd + r))), "wkv_a": arg((D, R + r)),
+             "kv_norm": arg((R,), F32), "wkv_b": arg((R, H * (hd + vd))),
+             "wo": arg((H * vd, D))}
+
+        def layer(x, w):
+            return LY.mla_attention(x, w, cfg, positions=jnp.arange(S))
+    else:
+        cfg = registry.config("granite-moe-1b-a400m")
+        B, S, D = 5, 2048, cfg.d_model
+        q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv * cfg.head_dim
+        w = {"wq": arg((D, q)), "wk": arg((D, kv)), "wv": arg((D, kv)),
+             "wo": arg((q, D))}
+        ctx = ShardCtx(tp=1, dp=1)
+
+        def layer(x, w):
+            return LY.attention(x, w, cfg, ctx, positions=jnp.arange(S))
+
+    def loss(x, w):
+        return jnp.sum(layer(x, w).astype(F32))
+
+    return loss, (arg((B, S, D)), w)
+
+
+@pytest.mark.parametrize("name", ["mla_attention", "attention"])
+def test_attention_compiles_fused(one_chip, monkeypatch, name):
+    """The forward and backward of both attention bodies at their cells'
+    shapes go through the fused kernel's three Mosaic calls (forward, dq,
+    dkv) and form no score row: no ``reduce-window`` (the row max the
+    compiler made of the materialized softmax) is left."""
+    _on_chip(monkeypatch)
+    loss, args = _attention_layer(name, one_chip)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "reduce-window" not in text
+
+
+def _trace_step(topo, arch, B, S, **over):
+    """Trace (not compile) the training step of ``arch`` at batch B of S
+    tokens on one described chip; returns the attention dispatch counts."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import repro.obs as obs
+    from repro.configs import registry
+    from repro.dist.collectives import QSyncConfig
+    from repro.models.sharding import ShardCtx
+    from repro.train import optim as O
+    from repro.train.trainer import TrainConfig, init_state, make_train_step
+
+    cfg = dataclasses.replace(registry.config(arch), **over)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    ctx = ShardCtx(tp=1, dp=1, qcfg=QSyncConfig(q=16, bucket=4096),
+                   grad_sync="lq")
+    oc, tc = O.OptConfig(name="adamw"), TrainConfig(steps=1, max_restarts=0)
+    step, spec, _ = make_train_step(cfg, ctx, mesh, oc, tc)
+    shapes = jax.eval_shape(lambda k: init_state(cfg, ctx, oc, tc, k),
+                            jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, spec, is_leaf=lambda x: isinstance(x, P))
+    rows = NamedSharding(mesh, P("data"))
+    batch = {k: jax.ShapeDtypeStruct((B, S), dt, sharding=rows)
+             for k, dt in (("tokens", jnp.int32), ("targets", jnp.int32),
+                           ("mask", F32))}
+    paths = ("fused", "materialized")
+    for p in paths:
+        obs.registry().counter("attention_dispatch", path=p).reset()
+    step.trace(state, batch)
+    return {p: obs.registry().value("attention_dispatch", path=p)
+            for p in paths}
+
+
+@pytest.mark.parametrize("cell,arch,B,S,over,want", [
+    # the leading dense layer and the scanned MoE layers' body
+    ("moonlight.ep8-8k", "moonlight-16b-a3b", 2, 8192,
+     {"n_layers": 5, "vocab": 20480, "experts_held": 8}, (2, 0)),
+    ("granite.dp1", "granite-moe-1b-a400m", 5, 2048, {"n_layers": 8},
+     (1, 0)),
+    # one query chunk: materialized
+    ("granite-512", "granite-moe-1b-a400m", 1, 512, {"n_layers": 8},
+     (0, 1)),
+    # a sliding window (2048) over 4096 tokens: materialized
+    ("recurrentgemma-4k", "recurrentgemma-9b", 1, 4096, {"n_layers": 3},
+     (0, 1)),
+])
+def test_step_attention_dispatch(topo, monkeypatch, cell, arch, B, S, over,
+                                 want):
+    """Which path every attention call of a training step traced for the
+    chip takes: fused in both training cells' steps, materialized for one
+    query chunk or a windowed attention."""
+    _on_chip(monkeypatch)
+    got = _trace_step(topo, arch, B, S, **over)
+    assert (got["fused"], got["materialized"]) == want, got
