@@ -13,16 +13,18 @@ an actual request/response protocol over real ``bytes``:
   transport staging bounded by one frame, independent of d);
 * :mod:`repro.agg.api`    — the unified :class:`AggNode` protocol
   (``ingest_frame`` / ``tick`` / ``published``) every aggregation endpoint
-  implements, plus the one composed :class:`AggConfig` knob surface;
+  implements;
 * :mod:`repro.agg.client` — encodes a local vector against a round's shared
   randomness, chunks it per the round MTU, and handles escalation +
   selective-retransmit responses;
-* :mod:`repro.agg.server` — streaming accumulator: validates/reassembles
-  arriving frames, drains payloads through ONE batched Pallas decode per
-  color space, sums in integer coordinate space (bit-deterministic under
-  any arrival order), NACKs undecodable clients with an escalated bound
-  (RobustAgreement r <- r^2, lattice granularity fixed) and incomplete
-  reassemblies with their missing chunk indices;
+* :mod:`repro.agg.intake` — the client intake the flat server and the
+  tree tiers share: validates/reassembles arriving frames, folds streamed
+  chunk ranges on the device, NACKs undecodable clients with an escalated
+  bound (RobustAgreement r <- r^2, lattice granularity fixed) and
+  incomplete reassemblies with their missing chunk indices;
+* :mod:`repro.agg.server` — streaming accumulator: drains payloads through
+  ONE batched Pallas decode per color space and sums in integer coordinate
+  space (bit-deterministic under any arrival order);
 * :mod:`repro.agg.service` — multi-round coordinator: round k+1's anchor is
   round k's published mean (digest-pinned in the RoundSpec) and its
   per-bucket y comes from round k's decode telemetry
@@ -53,7 +55,7 @@ from repro.agg.transport import (RoundSpec, FrameHeader, Payload, Response,
                                  STATUS_QUEUED, STATUS_NACK, STATUS_REJECT,
                                  STATUS_ACK, STATUS_RESEND, STATUS_RETRY,
                                  peek_route, Reassembler, ReassemblyStats)
-from repro.agg.api import AggConfig, AggNode, PublishedLog, PublishedRound
+from repro.agg.api import AggNode, PublishedLog, PublishedRound
 from repro.agg.client import AggClient
 from repro.agg.server import AggServer, RoundStats
 from repro.agg.service import (AggService, Round, RoundState, ServiceConfig)
@@ -71,6 +73,6 @@ __all__ = [
     "AggEngine", "EngineConfig", "PublishedRound", "Reassembler",
     "ReassemblyStats", "STATUS_QUEUED", "STATUS_NACK", "STATUS_REJECT",
     "STATUS_ACK", "STATUS_RESEND", "STATUS_RETRY", "peek_route",
-    "AggConfig", "AggNode", "PublishedLog", "AggTree", "TierAggregator",
+    "AggNode", "PublishedLog", "AggTree", "TierAggregator",
     "TierStats",
 ]

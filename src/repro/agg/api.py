@@ -16,14 +16,6 @@ Every aggregation endpoint in the repo — the single-round flat server
 A driver written against :class:`AggNode` cannot tell a flat star from a
 two-level tree from the overlapping-round engine: the sim and the examples
 drive all three through these verbs and assert bit-identical means.
-
-:class:`AggConfig` is the one composed knob surface: the round-contract
-fields that :class:`~repro.agg.service.ServiceConfig` owns, the
-cutover/drain policy that :class:`~repro.agg.engine.EngineConfig` owns, and
-the tree topology (``fanout``/``tiers``) in a single dataclass.  The
-``service_config()`` / ``engine_config()`` builders project it onto the
-layer configs; field defaults are asserted drift-free against the layer
-configs by ``tests/test_tree.py::test_config_defaults_no_drift``.
 """
 from __future__ import annotations
 
@@ -109,62 +101,3 @@ class AggNode(Protocol):
     def published(self) -> "list[PublishedRound]":
         """In-order outcomes of every round this node has published."""
         ...
-
-
-@dataclasses.dataclass(frozen=True)
-class AggConfig:
-    """One composed config for every aggregation topology.
-
-    The round-contract knobs (mirroring
-    :class:`~repro.agg.service.ServiceConfig`), the engine's cutover /
-    drain / admission policy (mirroring
-    :class:`~repro.agg.engine.EngineConfig`) and the tree topology live in
-    one place, so a tree tier is configured exactly like the root and a
-    knob can never drift silently between layers (defaults are asserted
-    equal field-by-field in the test suite).
-    """
-    # ---- round contract (ServiceConfig) ----
-    d: int
-    q: int = 16
-    bucket: int = 512
-    rotate: bool = False
-    y0: float = 1.0
-    seed: int = 0
-    max_attempts: int = 4
-    anchored: bool = True
-    mtu: int = 0
-    window: int = 0
-    y_decay: float = 0.75
-    y_escalate: float = 2.0
-    y_floor: float = 1e-6
-    # ---- cutover / drain / admission policy (EngineConfig) ----
-    quorum: int = 64
-    round_deadline: float = 1.0
-    min_clients: int = 1
-    straggler_deadline: float = 0.25
-    max_resends: int = 2
-    drain_deadline: float = 1.0
-    max_pending: Optional[int] = None
-    max_live_rounds: int = 3
-    # ---- tree topology (AggTree) ----
-    fanout: int = 8               # max children per aggregation node
-    tiers: int = 1                # tier layers between clients and the root
-
-    _SERVICE_FIELDS = ("d", "q", "bucket", "rotate", "y0", "seed",
-                       "max_attempts", "anchored", "mtu", "window",
-                       "y_decay", "y_escalate", "y_floor")
-    _ENGINE_FIELDS = ("quorum", "round_deadline", "min_clients",
-                      "straggler_deadline", "max_resends", "drain_deadline",
-                      "max_pending", "max_live_rounds")
-
-    def service_config(self):
-        """Project onto :class:`repro.agg.service.ServiceConfig`."""
-        from repro.agg.service import ServiceConfig
-        return ServiceConfig(
-            **{f: getattr(self, f) for f in self._SERVICE_FIELDS})
-
-    def engine_config(self):
-        """Project onto :class:`repro.agg.engine.EngineConfig`."""
-        from repro.agg.engine import EngineConfig
-        return EngineConfig(
-            **{f: getattr(self, f) for f in self._ENGINE_FIELDS})

@@ -60,7 +60,6 @@ import numpy as np
 
 import repro.obs as _obs
 from repro.agg import rounds
-from repro.agg.api import AggConfig
 from repro.agg.client import AggClient
 from repro.agg.engine import AggEngine, EngineConfig, PublishedRound
 from repro.agg.server import AggServer, RoundStats
@@ -458,11 +457,11 @@ class MultiRoundConfig:
     y_decay: float = 0.75
     seed: int = 0
 
-    def agg_config(self) -> AggConfig:
-        """Composed config; :func:`run_rounds` projects the service slice."""
-        return AggConfig(d=self.d, q=self.q, bucket=self.bucket, y0=self.y0,
-                         seed=self.seed, anchored=self.anchored,
-                         mtu=self.mtu, y_decay=self.y_decay)
+    def service_config(self) -> ServiceConfig:
+        return ServiceConfig(d=self.d, q=self.q, bucket=self.bucket,
+                             y0=self.y0, seed=self.seed,
+                             anchored=self.anchored, mtu=self.mtu,
+                             y_decay=self.y_decay)
 
 
 @dataclasses.dataclass
@@ -492,7 +491,7 @@ def run_rounds(cfg: MultiRoundConfig = MultiRoundConfig()
     # previous model state (both the anchored and unanchored services get
     # the same head start — the comparison isolates encode-side anchoring)
     anchor0 = mu + (cfg.y0 / 4) * rng.randn(cfg.d).astype(np.float32)
-    svc = AggService(cfg.agg_config().service_config(), anchor0=anchor0)
+    svc = AggService(cfg.service_config(), anchor0=anchor0)
     outcomes = []
     spread = cfg.spread0
     for _ in range(cfg.rounds):
@@ -590,24 +589,19 @@ class OpenLoopConfig:
                                    # non-terminal RETRYs
     seed: int = 0
 
-    def agg_config(self) -> AggConfig:
-        """The composed knob surface; the layer configs are projections of
-        this one object, so a knob cannot drift between service and engine."""
-        return AggConfig(
-            d=self.d, q=self.q, bucket=self.bucket, y0=self.y0,
-            seed=self.seed, anchored=True, mtu=self.mtu,
-            window=self.window, max_attempts=self.max_attempts,
+    def engine_config(self) -> EngineConfig:
+        return EngineConfig(
             quorum=self.quorum, round_deadline=self.round_deadline,
             min_clients=1, straggler_deadline=self.straggler_deadline,
             max_resends=self.max_resends, drain_deadline=self.drain_deadline,
             max_pending=self.max_pending,
             max_live_rounds=self.max_live_rounds)
 
-    def engine_config(self) -> EngineConfig:
-        return self.agg_config().engine_config()
-
     def service_config(self) -> ServiceConfig:
-        return self.agg_config().service_config()
+        return ServiceConfig(
+            d=self.d, q=self.q, bucket=self.bucket, y0=self.y0,
+            seed=self.seed, anchored=True, mtu=self.mtu,
+            window=self.window, max_attempts=self.max_attempts)
 
 
 @dataclasses.dataclass

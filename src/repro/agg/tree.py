@@ -12,10 +12,11 @@ identical stack a flat client/server pair uses):
     client 4 ─┼─> TierAggregator ─┘                       Pallas decode
     client 5 ─┘                                           per color space)
 
-A :class:`TierAggregator` accepts chunked client frames through the
-unchanged session/reassembly layer, validates CRCs and the sides sidecar
-against the round's pinned spec, and **sums accepted payloads' packed
-integer coordinates without ever decoding**:
+A :class:`TierAggregator` speaks the flat server's client protocol — it is
+the same :class:`repro.agg.intake.Intake`: frame validation, admission,
+reassembly, the streaming fold on the device, the escalation verdict and
+every response — and **sums accepted payloads' packed integer coordinates
+without ever decoding**:
 
 * The round's decode-reference coordinates ``k0 = round(ref/s - u)``
   (:func:`repro.agg.rounds.decode_ref_coords`) are bit-identical to the
@@ -25,9 +26,9 @@ integer coordinates without ever decoding**:
   NOT a decode dispatch), so ``k0 + r_i`` IS the root's decode output for
   that payload, obtained in pure int math.
 * The §5 checksum is verified per child in uint32 arithmetic:
-  ``h(k0 + r_i) == check_i``.  A mismatch draws the same NACK escalation
-  schedule as the flat server (q <- q^2, terminal REJECT at the cap), so
-  the tier's accepted set equals the flat server's for the same traffic.
+  ``h(k0 + r_i) == check_i``.  A mismatch draws the flat server's own
+  escalation verdict (q <- q^2, terminal REJECT at the cap), so the tier's
+  accepted set equals the flat server's for the same traffic.
 * Accepted residuals fold in place: ``R += r_i`` (int64 headroom),
   ``m += n_summed_i`` (children may themselves be tiers).  Admission is
   saturation-checked: a child whose fold would push ``max|R|`` past the
@@ -65,11 +66,10 @@ import numpy as np
 import repro.obs as _obs
 from repro.agg import rounds
 from repro.agg.api import PublishedRound
-from repro.agg.server import AggServer, _reject, _retry
+from repro.agg.intake import Intake, _reject, _StreamFold
+from repro.agg.server import AggServer
 from repro.agg.transport import chunks as C
 from repro.agg.transport import frame as wire
-from repro.agg.transport import session as S
-from repro.core import lattice as L
 from repro.kernels import ops as K
 
 # tier node ids live far above any realistic client id so the two can share
@@ -85,23 +85,6 @@ _MAX_PUMP = 64
 # ticks re-sends its full upstream frame sequence (recovers total loss of
 # the combined payload, where no reassembly exists upstream to RESEND)
 _UP_RESEND_TICKS = 2
-
-
-class _StreamFold:
-    """Speculative per-stream fold of a tier's streaming intake.
-
-    One per open ``(client, attempt, payload_crc)`` stream identity: the
-    int16 residuals folded so far (|r| <= q/2 <= 2^15 at the q=2^16 packing
-    cap, so int16 always fits) and the incrementally-accumulated §5
-    coordinate checksum (h(k) is linear in k, so per-range partial sums of
-    ``w_i * k_i`` compose exactly mod 2^32).  Nothing here has touched the
-    tier's sum — dropping the record IS the rollback."""
-    __slots__ = ("r", "check", "coords")
-
-    def __init__(self, padded: int):
-        self.r = np.zeros((padded,), np.int16)
-        self.check = 0          # the uint32 value, carried as a python int
-        self.coords = 0
 
 
 @dataclasses.dataclass
@@ -123,64 +106,42 @@ class TierStats:
     expired: int = 0
     drains: int = 0
     bytes_in: int = 0
+    peak_unvalidated_bytes: int = 0   # largest frame staged before its CRC
+    peak_pending_store_bytes: int = 0  # staged bodies + reassembly bytes
+    fold_syncs: int = 0          # streamed records read back (one per stream)
     up_frames_sent: int = 0      # upstream chunk frames (incl. retransmits)
     up_escalations: int = 0      # upstream NACKs honored (repack at next q)
     up_resends: int = 0          # upstream RESEND/timer retransmissions
 
 
-class TierAggregator:
+class TierAggregator(Intake):
     """One aggregation tier: a server to its children, a client upstream.
 
     Implements the :class:`repro.agg.api.AggNode` protocol.  ``anchor`` is
     the same out-of-band reference the root holds (digest-validated for
     anchored rounds); ``node_id`` is this tier's client id on the upstream
-    wire.
+    wire.  The child side is the flat server's :class:`Intake`, streaming
+    fold included: a windowed round folds each child stream's validated
+    word ranges on the device as they land, and commits into ``R`` only at
+    stream completion, after the §5 checksum and the saturation guard.
     """
 
     def __init__(self, spec: wire.RoundSpec, anchor, node_id: int,
-                 max_pending: "int | None" = None,
-                 streaming: "bool | None" = None):
-        """``streaming`` mirrors :class:`~repro.agg.server.AggServer`:
-        ``None`` resolves to ``spec.window > 0`` — a windowed round folds
-        each child stream's validated word ranges as they land (the tier
-        never decoded anyway, so streaming only moves the residual lift
-        from drain time to arrival time and frees the chunk bytes early);
-        commit into ``R`` still happens only at stream completion, after
-        the §5 checksum and the saturation guard (which needs the full
-        residual vector) pass."""
-        rounds.check_anchor(spec, anchor if spec.anchored else None)
-        self.spec = spec
+                 max_pending: "int | None" = None):
+        super().__init__(
+            spec, anchor, max_pending, None,
+            obs=_obs.scope("agg_tier", round=spec.round_id, node=node_id),
+            stats=TierStats())
         self.node_id = node_id
-        self.max_pending = max_pending
-        self._sealed = False
-        self._next_round_id = 0
-        # the integer-space lift reference: bit-identical to the k_a inside
-        # the root's batched decode (both anchored and unanchored rounds)
-        self._k0 = np.asarray(rounds.decode_ref_coords(
-            spec, None if spec.anchored else anchor), np.int32)
+        # the integer-space lift reference on the host: bit-identical to the
+        # k_a inside the root's batched decode (anchored and unanchored)
+        self._k0_host = np.asarray(self._k0, np.int64)
         self._weights = np.asarray(rounds.checksum_weights(spec), np.uint32)
         self._sides_np = spec.sides_np()
         # escalation headroom: the widest color space any attempt may use;
         # |R| must stay inside its centered range or the repacked colors
         # would alias and the root's decode would silently wrap
         self._q_max = wire.q_at_attempt(spec.cfg.q, spec.max_attempts - 1)
-        # ---- child side (mirrors AggServer's intake) ----
-        self._admitted: set[int] = set()
-        self._accepted: set[int] = set()
-        self._gave_up: set[int] = set()
-        self._pending: dict[int, wire.Payload] = {}
-        self._attempt_floor: dict[int, int] = {}
-        self._folds: "dict[tuple, _StreamFold]" = {}
-        self._streaming = ((spec.window > 0) if streaming is None
-                           else bool(streaming)) and spec.mtu > 0
-        if self._streaming:
-            self._k0_j = jnp.asarray(self._k0)
-            self._rx = S.Reassembler(spec,
-                                     on_range_validated=self._fold_range,
-                                     on_stream_discarded=self._drop_stream)
-        else:
-            self._rx = S.Reassembler(spec)
-        self._margins: dict[int, tuple] = {}
         # ---- the sum-without-decode accumulator ----
         self._R = np.zeros((spec.padded,), np.int64)
         self._m = 0
@@ -192,18 +153,6 @@ class TierAggregator:
         self._up_gave_up = False
         self._up_idle_ticks = 0
         self.retry_round: Optional[int] = None
-        # tier accounting lives in an obs scope (exported registry counters
-        # when metrics are on, a detached registry otherwise); the TierStats
-        # dataclass callers read is filled from it on access
-        self._obs = _obs.scope("agg_tier", round=spec.round_id,
-                               node=node_id)
-        self._stats = TierStats()
-
-    @property
-    def stats(self) -> TierStats:
-        """This tier's telemetry, materialized from the obs scope."""
-        self._obs.fill(self._stats)
-        return self._stats
 
     @property
     def tier_index(self) -> int:
@@ -216,7 +165,7 @@ class TierAggregator:
         or an upstream response (returns the frames to send next)."""
         if data[:4] == wire.MAGIC_RESPONSE:
             return self.handle_upstream(data)
-        return [self.ingest_child(data)]
+        return [self.receive(data)]
 
     def tick(self, now: float = 0.0) -> "list[bytes]":
         """Fold staged children, chase missing chunks, forward upstream."""
@@ -229,103 +178,13 @@ class TierAggregator:
         return []
 
     # ---------------------------------------------------------- CHILD SIDE
-    def ingest_child(self, data: bytes) -> bytes:
-        """Handle one arriving child frame; returns the response bytes.
-
-        Identical admission/session behavior to :meth:`AggServer.receive`:
-        framing and spec violations draw wire/spec REJECTs, chunked bodies
-        reassemble out of order through the session layer, duplicates ACK
-        idempotently, and a sealed tier or full pending store answers a
-        non-terminal RETRY.
-        """
-        self._obs.inc("received")
-        self._obs.inc("bytes_in", len(data))
-        try:
-            h, chunk = wire.decode_frame(data)
-        except wire.WireError:
-            self._obs.inc("rejected_wire")
-            return self._respond(_reject(self.spec, 0xFFFFFFFF))
-        try:
-            wire.check_frame_against_spec(h, self.spec, len(chunk))
-        except wire.HeaderMismatchError:
-            self._obs.inc("rejected_spec")
-            return self._respond(_reject(self.spec, h.client_id,
-                                         round_id=h.round_id))
-        if _obs.tracing_enabled():
-            _obs.tracer().event("chunk",
-                                parent=("client", h.round_id, h.client_id),
-                                round=h.round_id, client=h.client_id,
-                                tier=self.node_id, chunk=h.chunk_index,
-                                n_chunks=h.n_chunks)
-        if h.client_id in self._gave_up:
-            return self._respond(_reject(self.spec, h.client_id))
-        if h.client_id in self._accepted:
-            self._obs.inc("duplicates")
-            return self._respond(self._ack(h.client_id))
-        if h.client_id not in self._admitted:
-            if self._sealed:
-                self._obs.inc("retried")
-                return self._respond(_retry(h.round_id, h.client_id,
-                                            h.attempt, self._next_round_id))
-            if (self.max_pending is not None
-                    and self.occupancy >= self.max_pending):
-                self._obs.inc("retried")
-                return self._respond(_retry(h.round_id, h.client_id,
-                                            h.attempt, self.spec.round_id))
-            self._admitted.add(h.client_id)
-        if h.n_chunks == 1:
-            p = wire.payload_from_body(h, chunk)
-        else:
-            if h.attempt < self._attempt_floor.get(h.client_id, 0):
-                # stale chunk of an attempt this tier already NACKed must
-                # not re-open a dead reassembly stream
-                self._obs.inc("duplicates")
-                return self._respond(self._queued(h, slim=True))
-            event, p = self._rx.add(h, chunk)
-            if event == S.REJECT:
-                self._obs.inc("resends_sent")
-                return self._respond(wire.Response(
-                    status=wire.STATUS_RESEND,
-                    round_id=self.spec.round_id, client_id=h.client_id,
-                    attempt_next=h.attempt, q_next=h.q,
-                    y_next=wire.y_at_attempt(self.spec, h.attempt),
-                    missing=tuple(range(h.n_chunks)),
-                    credit=self.spec.window))
-            if p is None:                   # PROGRESS / DUPLICATE / STALE
-                if event in (S.DUPLICATE, S.STALE):
-                    self._obs.inc("duplicates")
-                return self._respond(self._queued(h, slim=True))
-            if p.streamed:
-                # stream complete + sealed: verify the incremental fold and
-                # commit into R now (the tier's per-child drain)
-                return self._finish_streamed(h, p)
-        try:
-            wire.check_sides_against_spec(p, self.spec)
-        except wire.HeaderMismatchError:
-            self._obs.inc("rejected_spec")
-            return self._respond(_reject(self.spec, p.client_id))
-        prev = self._pending.get(p.client_id)
-        if prev is not None and prev.attempt >= p.attempt:
-            self._obs.inc("duplicates")
-        else:
-            self._pending[p.client_id] = p
-            self._obs.inc("queued")
-            if _obs.tracing_enabled():
-                _obs.tracer().event(
-                    "seal", parent=("client", h.round_id, p.client_id),
-                    round=h.round_id, client=p.client_id,
-                    tier=self.node_id, attempt=p.attempt)
-        return self._respond(self._queued(h))
-
     def drain_children(self) -> "list[bytes]":
         """Verify + fold every staged child payload; returns verdicts.
 
-        The sum-without-decode core: per payload, residual-lift the packed
-        colors about ``k0`` (integer-only), verify the §5 checksum over
-        ``k0 + r`` in uint32 math, saturation-check the fold against the
-        escalation cap's coordinate range, and add the residuals into the
-        int64 accumulator.  No decode dispatch is issued — asserted via
-        ``ops.DISPATCH_COUNTS`` in the tests.
+        Per payload, residual-lift the packed colors about ``k0``
+        (integer-only) and hand them to :meth:`_verify_and_fold`.  No decode
+        dispatch is issued — asserted via ``ops.DISPATCH_COUNTS`` in the
+        tests.
         """
         if not self._pending:
             return self._resend_requests()
@@ -334,224 +193,69 @@ class TierAggregator:
             "fold", parent=("round", self.spec.round_id),
             round=self.spec.round_id, tier=self.node_id,
             payloads=len(self._pending)) if _obs.tracing_enabled() else None
-        staged = sorted(self._pending.values(), key=lambda p: p.client_id)
-        self._pending.clear()
         responses = []
-        for p in staged:
-            r = np.asarray(K.lattice_residuals(
-                jnp.asarray(p.words), jnp.asarray(self._k0), q=p.q),
-                np.int64)
-            k_hat = self._k0.astype(np.int64) + r
-            chk = int(np.sum(
-                k_hat.astype(np.int32).view(np.uint32) * self._weights,
-                dtype=np.uint32))
-            if chk != (p.check & 0xFFFFFFFF):
-                responses.append(self._decode_failure(p))
-                continue
-            cand = self._R + r
-            half = self._q_max // 2
-            if cand.max() >= half or cand.min() < -half:
-                # folding this child would push the combined coordinates
-                # outside the widest escalation attempt's centered range —
-                # the repacked colors would alias.  Terminal for the child
-                # at THIS tier (it may enroll flat in a later round).
-                self._obs.inc("saturated")
-                self._obs.inc("gave_up")
-                self._gave_up.add(p.client_id)
-                self._rx.discard(p.client_id)
-                if fold_sp is not None:
-                    _obs.tracer().event(
-                        "saturation_reject", parent=fold_sp.span_id,
-                        round=self.spec.round_id, tier=self.node_id,
-                        client=p.client_id)
-                _obs.trigger("saturation_reject", at=_obs.tracer().now(),
-                             round=self.spec.round_id, tier=self.node_id,
-                             client=p.client_id)
-                responses.append(self._respond(_reject(self.spec,
-                                                       p.client_id)))
-                continue
-            self._R = cand
-            self._m += p.n_summed
-            self._obs.inc("accepted")
-            self._obs.inc("clients_summed", p.n_summed)
-            self._accepted.add(p.client_id)
-            self._rx.discard(p.client_id)
-            responses.append(self._respond(self._ack(p.client_id)))
+        for p in self._take_pending():
+            r = np.asarray(K.lattice_residuals(jnp.asarray(p.words),
+                                               self._k0, q=p.q), np.int64)
+            responses.append(self._respond(self._verify_and_fold(p, r)))
         if fold_sp is not None:
             _obs.tracer().end(fold_sp, folded=self._m)
         return responses + self._resend_requests()
 
-    # ------------------------------------------------------- STREAMING RX
-    def _fold_range(self, h: wire.FrameHeader, word_start: int,
-                    words: np.ndarray) -> None:
-        """``on_range_validated``: residual-lift one validated word range
-        into the stream's speculative record (same integer identity the
-        batched fold uses); the session frees the chunk bytes after this."""
-        key = (h.client_id, h.attempt, h.payload_crc)
-        rec = self._folds.get(key)
-        if rec is None:
-            rec = self._folds[key] = _StreamFold(self.spec.padded)
-        c0 = word_start * (32 // L.bits_for_q(h.q))
-        r = np.asarray(K.lattice_residuals_range(
-            jnp.asarray(words), self._k0_j, q=h.q, word_start=word_start))
-        n = r.shape[0]
-        rec.r[c0:c0 + n] = r.astype(np.int16)
-        rec.coords += n
-        k = r.astype(np.int64) + self._k0.astype(np.int64)[c0:c0 + n]
-        part = np.sum(k.astype(np.uint32) * self._weights[c0:c0 + n],
-                      dtype=np.uint32)
-        rec.check = (rec.check + int(part)) & 0xFFFFFFFF
+    def _commit_stream(self, h: wire.FrameHeader, p: wire.Payload,
+                       rec: _StreamFold) -> wire.Response:
+        """A child stream completed and sealed: read its record back once
+        and verify and fold it like a staged payload."""
+        r = np.asarray(rec.r, np.int64)
+        self._obs.inc("fold_syncs")
+        return self._verify_and_fold(p, r, ack=h.n_chunks)
 
-    def _drop_stream(self, h: wire.FrameHeader) -> None:
-        """``on_stream_discarded``: drop the speculative record — nothing
-        was committed to R, so this IS the rollback."""
-        self._folds.pop((h.client_id, h.attempt, h.payload_crc), None)
-
-    def _finish_streamed(self, h: wire.FrameHeader,
-                         p: wire.Payload) -> bytes:
-        """A child stream completed and its payload-CRC seal held: verify
-        the incremental §5 checksum and the saturation guard (which needs
-        the FULL residual vector — the record has it), then fold into R."""
-        rec = self._folds.pop((h.client_id, h.attempt, h.payload_crc), None)
-        try:
-            wire.check_sides_against_spec(p, self.spec)
-        except wire.HeaderMismatchError:
-            self._obs.inc("rejected_spec")
-            return self._respond(_reject(self.spec, p.client_id))
-        if rec is None or rec.coords != self.spec.padded:
-            self._obs.inc("resends_sent")
-            return self._respond(wire.Response(
-                status=wire.STATUS_RESEND, round_id=self.spec.round_id,
-                client_id=h.client_id, attempt_next=h.attempt, q_next=h.q,
-                y_next=wire.y_at_attempt(self.spec, h.attempt),
-                missing=tuple(range(h.n_chunks)), credit=self.spec.window))
-        if rec.check != (h.check & 0xFFFFFFFF):
-            return self._decode_failure(p)
-        cand = self._R + rec.r.astype(np.int64)
+    def _verify_and_fold(self, p: wire.Payload, r: np.ndarray,
+                         ack: int = 0) -> wire.Response:
+        """The sum-without-decode core for one child whose residuals about
+        ``k0`` are ``r`` (int64): verify the §5 checksum over ``k0 + r`` in
+        uint32 math, saturation-check the fold against the escalation cap's
+        coordinate range, and add the residuals into the int64
+        accumulator.  Returns the child's verdict."""
+        k_hat = (self._k0_host + r).astype(np.int32)
+        chk = int(np.sum(k_hat.view(np.uint32) * self._weights,
+                         dtype=np.uint32))
+        if chk != (p.check & 0xFFFFFFFF):
+            return self._escalate(p.client_id, p.attempt, p.q)
+        cand = self._R + r
         half = self._q_max // 2
         if cand.max() >= half or cand.min() < -half:
+            # folding this child would push the combined coordinates
+            # outside the widest escalation attempt's centered range — the
+            # repacked colors would alias.  Terminal for the child at THIS
+            # tier (it may enroll flat in a later round).
             self._obs.inc("saturated")
             self._obs.inc("gave_up")
-            self._gave_up.add(h.client_id)
+            self._gave_up.add(p.client_id)
+            self._rx.discard(p.client_id)
             if _obs.tracing_enabled():
                 _obs.tracer().event(
                     "saturation_reject",
                     parent=("round", self.spec.round_id),
                     round=self.spec.round_id, tier=self.node_id,
-                    client=h.client_id)
+                    client=p.client_id)
             _obs.trigger("saturation_reject", at=_obs.tracer().now(),
                          round=self.spec.round_id, tier=self.node_id,
-                         client=h.client_id)
-            return self._respond(_reject(self.spec, h.client_id))
+                         client=p.client_id)
+            return _reject(self.spec, p.client_id)
         self._R = cand
-        self._m += h.n_summed
+        self._m += p.n_summed
         self._obs.inc("accepted")
-        self._obs.inc("clients_summed", h.n_summed)
-        self._accepted.add(h.client_id)
-        return self._respond(self._ack(h.client_id, ack=h.n_chunks))
-
-    def _decode_failure(self, p: wire.Payload) -> bytes:
-        """The flat server's escalation schedule, verbatim: NACK to the
-        next attempt, terminal REJECT at the color-space cap."""
-        self._obs.inc("decode_failures")
-        nxt = p.attempt + 1
-        if p.q >= wire.Q_CAP or nxt >= self.spec.max_attempts:
-            self._gave_up.add(p.client_id)
-            self._rx.discard(p.client_id)
-            self._obs.inc("gave_up")
-            return self._respond(_reject(self.spec, p.client_id))
-        self._obs.inc("nacks_sent")
-        self._attempt_floor[p.client_id] = nxt
-        return self._respond(wire.Response(
-            status=wire.STATUS_NACK, round_id=self.spec.round_id,
-            client_id=p.client_id, attempt_next=nxt,
-            q_next=wire.q_at_attempt(self.spec.cfg.q, nxt),
-            y_next=wire.y_at_attempt(self.spec, nxt),
-            y_buckets=self._margin_tuple(nxt), credit=self.spec.window))
-
-    def _margin_tuple(self, attempt: int) -> tuple:
-        t = self._margins.get(attempt)
-        if t is None:
-            t = tuple(float(v) for v in
-                      wire.y_buckets_at_attempt(self.spec, attempt))
-            self._margins[attempt] = t
-        return t
-
-    def _queued(self, h: wire.FrameHeader,
-                slim: bool = False) -> wire.Response:
-        return wire.Response(
-            status=wire.STATUS_QUEUED, round_id=self.spec.round_id,
-            client_id=h.client_id, attempt_next=h.attempt, q_next=h.q,
-            y_next=wire.y_at_attempt(self.spec, h.attempt),
-            y_buckets=() if slim else self._margin_tuple(h.attempt),
-            ack=self._rx.high_water(h.client_id) if self.spec.window else 0,
-            credit=self.spec.window)
-
-    def _ack(self, client_id: int, ack: int = 0) -> wire.Response:
-        return wire.Response(status=wire.STATUS_ACK,
-                             round_id=self.spec.round_id,
-                             client_id=client_id, attempt_next=0, q_next=0,
-                             y_next=0.0, ack=ack, credit=self.spec.window)
-
-    def _respond(self, r: wire.Response) -> bytes:
-        return wire.encode_response(r)
-
-    def _resend_requests(self) -> "list[bytes]":
-        out = []
-        for cid, (attempt, missing) in self._rx.incomplete().items():
-            self._obs.inc("resends_sent")
-            out.append(self._respond(wire.Response(
-                status=wire.STATUS_RESEND, round_id=self.spec.round_id,
-                client_id=cid, attempt_next=attempt,
-                q_next=wire.q_at_attempt(self.spec.cfg.q, attempt),
-                y_next=wire.y_at_attempt(self.spec, attempt),
-                y_buckets=self._margin_tuple(attempt), missing=missing,
-                ack=self._rx.high_water(cid) if self.spec.window else 0,
-                credit=self.spec.window)))
-        return out
+        self._obs.inc("clients_summed", p.n_summed)
+        self._accepted.add(p.client_id)
+        self._rx.discard(p.client_id)
+        return self._ack(p.client_id, ack=ack)
 
     # ----------------------------------------------------------- LIFECYCLE
-    def seal(self, next_round_id: int = 0) -> None:
-        """Stop admitting NEW children (cutover); admitted children keep
-        full service.  Once every admitted child resolves, the next tick
-        forwards the combined payload upstream."""
-        self._sealed = True
-        self._next_round_id = next_round_id
-
-    @property
-    def sealed(self) -> bool:
-        return self._sealed
-
-    @property
-    def admitted_count(self) -> int:
-        return len(self._admitted)
-
-    @property
-    def unresolved(self) -> frozenset:
-        return frozenset(self._admitted - self._accepted - self._gave_up)
-
-    @property
-    def occupancy(self) -> int:
-        return len(set(self._pending) | self._rx.open_clients())
-
-    @property
-    def accepted_clients(self) -> frozenset:
-        return frozenset(self._accepted)
-
     @property
     def n_summed(self) -> int:
         """Clients folded into the accumulator so far."""
         return self._m
-
-    def expire_client(self, client_id: int) -> None:
-        """Drop an unresolved straggler's state without a verdict."""
-        if (client_id not in self._admitted or client_id in self._accepted
-                or client_id in self._gave_up):
-            return
-        self._pending.pop(client_id, None)
-        self._rx.discard(client_id)
-        self._admitted.discard(client_id)
-        self._obs.inc("expired")
 
     @property
     def forwarded_q(self) -> "int | None":
@@ -593,7 +297,7 @@ class TierAggregator:
         cached = self._up_frames.get(attempt)
         if cached is None:
             q = wire.q_at_attempt(self.spec.cfg.q, attempt)
-            k_fwd = (self._k0.astype(np.int64) + self._R).astype(np.int32)
+            k_fwd = (self._k0_host + self._R).astype(np.int32)
             words = np.asarray(K.lattice_pack_coords(jnp.asarray(k_fwd),
                                                      q=q))
             check = int(np.sum(k_fwd.view(np.uint32) * self._weights,
@@ -753,7 +457,7 @@ class AggTree:
         clients directly, the root never sees individual client traffic)."""
         peek = wire.peek_route(data)
         leaf = self._leaf_for(peek[1]) if peek else self._leaf[0]
-        return [leaf.ingest_child(data)]
+        return [leaf.receive(data)]
 
     def tick(self, now: float = 0.0) -> "list[bytes]":
         """Fire every node's policy and pump internal traffic until

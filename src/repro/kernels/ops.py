@@ -190,31 +190,6 @@ def lattice_residuals(words: jax.Array, k0: jax.Array, *,
     return _residuals_jit(words, k0, q=q, n=k0.shape[0])
 
 
-def lattice_residuals_range(words: jax.Array, k0: jax.Array, *, q: int,
-                            word_start: int = 0) -> jax.Array:
-    """Residuals of a word-aligned SLICE of a packed payload: the streaming
-    drain's range-fold primitive (repro.agg.server / repro.agg.tree).
-
-    ``words`` is the contiguous run of packed uint32 words
-    ``[word_start, word_start + words.shape[-1])`` of the full payload —
-    e.g. the validated chunk prefix a reassembly session just committed —
-    and ``k0`` the FULL (n,) int32 reference-coordinate vector; the slice
-    arithmetic (word w covers coordinates ``[w*per, (w+1)*per)``) lives
-    here so every caller folds against the identical reference window.
-    Returns (..., m) int32 residuals for coordinates
-    ``[word_start*per, word_start*per + m)`` with ``m`` clipped to n, such
-    that concatenating the ranges of a whole payload reproduces
-    :func:`lattice_residuals` of that payload bit for bit.  Like the
-    full-payload fold it is deliberately NOT a counted decode dispatch."""
-    per = 32 // L.bits_for_q(q)
-    c0 = word_start * per
-    if c0 >= k0.shape[0]:
-        raise ValueError(f"word_start {word_start} starts at coordinate "
-                         f"{c0}, past the {k0.shape[0]}-coordinate vector")
-    m = min(words.shape[-1] * per, k0.shape[0] - c0)
-    return _residuals_jit(words, k0[c0:c0 + m], q=q, n=m)
-
-
 @partial(jax.jit, static_argnames=("q",))
 def _pack_coords_jit(k, *, q: int):
     return _ref.lattice_pack_coords_ref(k, q=q, bits=L.bits_for_q(q))

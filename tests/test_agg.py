@@ -300,18 +300,22 @@ def test_server_mean_invariant_to_arrival_order_and_drain_batching():
     assert float(np.abs(means[0] - exact).max()) <= spec.y0
 
 
-def test_server_duplicates_never_double_count():
+def test_server_duplicates_never_double_count(intake_node):
+    """Duplicates of accepted clients are ACKed and never counted twice, by
+    the flat server and by a tree tier alike; in a windowed round the ACK
+    covers every chunk (``ack=n_chunks``)."""
     spec = _spec(d=1024, bucket=128)
     base, xs, payloads = _fleet(spec, 8)
-    server = AggServer(spec, base)
+    node = intake_node(spec, base)
     for p in payloads:
-        server.receive(p)
-    server.drain()
+        node.receive(p)
+    node.tick()
     for p in payloads[:5]:                  # post-accept duplicates: ACKed
-        r = wire.decode_response(server.receive(p))
+        r = wire.decode_response(node.receive(p))
         assert r.status == wire.STATUS_ACK
-    server.receive(payloads[6])             # pre-drain duplicate window
-    mean, stats = server.finalize()
+    node.receive(payloads[6])               # one more accepted duplicate
+    mean = intake_node.publish(node, base)
+    stats = node.stats
     ref_server = AggServer(spec, base)
     for p in payloads:
         ref_server.receive(p)
@@ -320,8 +324,20 @@ def test_server_duplicates_never_double_count():
     assert stats.duplicates == 6
     assert stats.accepted == 8
 
+    windowed = dataclasses.replace(spec, mtu=200, window=2)
+    frames = sim.fleet_frames(windowed, xs[:2])
+    node = intake_node(windowed, base)
+    for fs in frames:
+        for f in fs:
+            node.receive(f)
+    assert node.accepted_clients == frozenset({0, 1})
+    r = wire.decode_response(node.receive(frames[0][1]))
+    assert r.status == wire.STATUS_ACK
+    assert r.ack == len(frames[0]) > 1
+    assert node.stats.duplicates == 1 and node.stats.accepted == 2
 
-def test_server_escalation_recovers_and_gives_up():
+
+def test_server_escalation_recovers_and_gives_up(intake_node):
     spec = _spec(d=1024, bucket=128, y0=1.0, max_attempts=4)
     rng = np.random.RandomState(0)
     base = rng.randn(spec.d).astype(np.float32)
@@ -330,10 +346,10 @@ def test_server_escalation_recovers_and_gives_up():
         1: AggClient(spec, 1, base + 8.0),     # needs q=256 (margin 17*y0)
         2: AggClient(spec, 2, base + 1e6),     # beyond the q cap: dropped
     }
-    server = AggServer(spec, base)
+    server = intake_node(spec, base)
     for c in clients.values():
         server.receive(c.payload())
-    resps = server.drain()
+    resps = server.tick()
     while resps:
         retries = [p for rb in resps
                    for p in clients[wire.decode_response(rb).client_id]
@@ -342,8 +358,9 @@ def test_server_escalation_recovers_and_gives_up():
             break
         for p in retries:
             server.receive(p)
-        resps = server.drain()
-    mean, stats = server.finalize()
+        resps = server.tick()
+    mean = intake_node.publish(server, base)
+    stats = server.stats
     assert sorted(server.accepted_clients) == [0, 1]
     assert clients[1].attempt == 1 and not clients[1].gave_up
     assert clients[2].gave_up and stats.gave_up == 1

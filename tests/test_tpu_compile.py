@@ -111,7 +111,8 @@ def test_stream_fold_compiles_in_place(one_chip, q):
     """The streaming drain's per-range fold at a 64 KiB range writes into
     the donated (N,) int16 record in place, and the commit over the whole
     record compiles beside it."""
-    from repro.agg.server import _commit_math, _fold_range_math
+    from repro.agg.intake import _fold_range_math
+    from repro.agg.server import _commit_math
     nw, bucket = (1 << 16) // 4, 4096
     n = nw * 32 // L.bits_for_q(q)
 

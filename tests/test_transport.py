@@ -368,7 +368,7 @@ def test_multi_round_service_runs_chunked():
     assert outs[0].bytes_per_client == wire.payload_bytes(spec)
 
 
-def test_payload_crc_seal_failure_is_retryable():
+def test_payload_crc_seal_failure_is_retryable(intake_node):
     """Regression (review finding): a forged chunk that shares the honest
     stream's exact header and poisons the body draws a RESEND-all, never a
     terminal REJECT — the honest client rebuilds and is accepted."""
@@ -378,7 +378,7 @@ def test_payload_crc_seal_failure_is_retryable():
     h1, chunk1 = wire.decode_frame(frames[1])
     poisoned = wire.encode_frame(h1, bytes(len(chunk1)))   # garbage body
     c = AggClient(spec, 0, np.asarray(xs[0]))
-    server = AggServer(spec, base)
+    server = intake_node(spec, base)
     server.receive(poisoned)              # commits garbage at index 1
     last = None
     for f in frames:                      # honest index 1 drops as dup
@@ -391,7 +391,7 @@ def test_payload_crc_seal_failure_is_retryable():
     assert not c.gave_up and len(resend) == len(frames)
     for f in resend:
         server.receive(f)
-    server.drain()
+    server.tick()
     assert server.accepted_clients == frozenset({0})
 
 
@@ -702,7 +702,7 @@ def test_streaming_bit_parity_any_permutation_with_duplicates():
             (trial, stats.peak_pending_store_bytes)
 
 
-def test_streaming_seal_failure_rolls_back_speculative_fold():
+def test_streaming_seal_failure_rolls_back_speculative_fold(intake_node):
     """A stream whose payload-CRC seal fails (forged body byte under a
     recomputed frame CRC) must contribute NOTHING: the speculative fold is
     dropped, the client is RESENT the whole sequence, and the rebuilt
@@ -718,7 +718,7 @@ def test_streaming_seal_failure_rolls_back_speculative_fold():
     forged_body = bytearray(chunk1)
     forged_body[3] ^= 0xFF
     forged = wire.encode_frame(h1, bytes(forged_body))  # valid frame CRC,
-    server = AggServer(spec, base)                      # lying body
+    server = intake_node(spec, base)                    # lying body
     server.receive(fleets[0][0])
     server.receive(forged)
     for f in fleets[0][2:]:
@@ -733,8 +733,8 @@ def test_streaming_seal_failure_rolls_back_speculative_fold():
         server.receive(f)
     for f in fleets[1]:
         server.receive(f)
-    server.drain()
-    mean, _ = server.finalize()
+    server.tick()
+    mean = intake_node.publish(server, base)
     assert server.accepted_clients == frozenset(range(2))
     assert np.array_equal(mean.view(np.uint32), mean_ref.view(np.uint32))
 

@@ -1,7 +1,7 @@
 """Hierarchical sum-without-decode tree (ISSUE 7): tree-vs-flat bit-parity
 under chunk loss / reordering / duplicates / straggling tiers, saturation
-rejection at the q cap, the no-tier-decodes dispatch gate, the AggNode
-protocol surface, and AggConfig default-drift protection."""
+rejection at the q cap, the no-tier-decodes dispatch gate and the AggNode
+protocol surface."""
 import os
 import subprocess
 import sys
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.agg import sim
-from repro.agg.api import AggConfig, AggNode, PublishedRound
+from repro.agg.api import AggNode, PublishedRound
 from repro.agg.client import AggClient
 from repro.agg.engine import AggEngine, EngineConfig
 from repro.agg.server import AggServer
@@ -273,7 +273,7 @@ def test_no_tier_decodes_root_decodes_once_per_color_space():
 
 
 # ---------------------------------------------------------------------------
-# AggNode protocol + config drift
+# AggNode protocol
 # ---------------------------------------------------------------------------
 
 def test_aggnode_protocol_is_satisfied_by_all_endpoints():
@@ -308,44 +308,6 @@ def test_tree_behind_protocol_matches_flat_server_driver():
 
     _assert_parity(drive(AggTree(spec, base, fanout=4)),
                    drive(AggServer(spec, base)))
-
-
-def test_config_defaults_no_drift():
-    """AggConfig mirrors ServiceConfig + EngineConfig field-by-field; a
-    default changed in one layer but not the composed config fails here."""
-    import dataclasses as dc
-
-    svc_defaults = {f.name: f.default for f in dc.fields(ServiceConfig)
-                    if f.default is not dc.MISSING}
-    eng_defaults = {f.name: f.default for f in dc.fields(EngineConfig)
-                    if f.default is not dc.MISSING}
-    agg_defaults = {f.name: f.default for f in dc.fields(AggConfig)
-                    if f.default is not dc.MISSING}
-    for name in AggConfig._SERVICE_FIELDS:
-        if name in svc_defaults:
-            assert agg_defaults[name] == svc_defaults[name], name
-    for name in AggConfig._ENGINE_FIELDS:
-        assert agg_defaults[name] == eng_defaults[name], name
-    # projections carry every mirrored field across verbatim
-    cfg = AggConfig(d=512)
-    sc, ec = cfg.service_config(), cfg.engine_config()
-    for name in AggConfig._SERVICE_FIELDS:
-        assert getattr(sc, name) == getattr(cfg, name), name
-    for name in AggConfig._ENGINE_FIELDS:
-        assert getattr(ec, name) == getattr(cfg, name), name
-
-
-def test_tree_from_agg_config_topology():
-    """The composed AggConfig carries tree topology alongside the round
-    contract, and a tree built from it matches flat bit-for-bit."""
-    cfg = AggConfig(d=512, bucket=64, fanout=4, tiers=1)
-    spec = _spec(d=cfg.d, bucket=cfg.bucket, q=cfg.q)
-    base, _, frames = _fleet(spec, 8)
-    pf = _flat_publish(spec, base, frames)
-    pt = _run_tree(AggTree(spec, base, fanout=cfg.fanout, tiers=cfg.tiers),
-                   frames)
-    _assert_parity(pt, pf)
-    assert pt.round_id == pf.round_id == spec.round_id
 
 
 # ---------------------------------------------------------------------------
