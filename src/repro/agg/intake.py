@@ -17,7 +17,10 @@ duplicate tolerant, committing each validated chunk in place so the
 transport never stages more than one frame (header + MTU) of unvalidated
 bytes, independent of d.  Either way the node buffers the *packed words* —
 the 8x-compressed form — until a drain; a completed reassembly hands the
-drain the same zero-copy Payload view a single frame would have.
+drain the same zero-copy Payload view a single frame would have.  As a
+payload enters the pending store the node's :meth:`Intake._stage_pending`
+hook runs: the flat server starts the words' copy to the device there, a
+tier (which drains on the host) does nothing.
 
 **Streaming drain** (on when ``RoundSpec.window > 0``): the
 seal-then-stage path above is replaced for multi-chunk payloads.  The
@@ -314,6 +317,7 @@ class Intake:
                 self._pending_bytes -= prev.words.nbytes + prev.sides.nbytes
             self._pending[p.client_id] = p
             self._pending_bytes += p.words.nbytes + p.sides.nbytes
+            self._stage_pending(p)
             self._note_pending_store()
             self._obs.inc("queued")
             if _obs.tracing_enabled():
@@ -490,6 +494,12 @@ class Intake:
         """Verify a complete, sealed stream's record and commit it into the
         node's sum; returns the client's verdict."""
         raise NotImplementedError
+
+    def _stage_pending(self, p: wire.Payload) -> None:
+        """Called as ``p`` enters the pending store, first or as a higher
+        attempt replacing the client's pending payload: a node that drains
+        on the device starts moving the words there now.  Nothing here; a
+        node that drains on the host stages nothing."""
 
     def _take_pending(self) -> "list[wire.Payload]":
         """Empty the pending store for a drain, in client-id order."""

@@ -12,14 +12,22 @@ candidate accumulator, and one readback of the small outputs gives the
 verdict.  ``RoundStats.fold_syncs`` counts these blocking readbacks (one
 per completed stream).
 
-Drain path (:meth:`AggServer.drain`): all pending payloads of one color
-space q are decoded against the server's decode reference in ONE batched
-Pallas launch (repro.kernels.ops.lattice_decode_batched), their §5
-coordinate checksums verified vectorized, and the accepted senders' integer
-lattice coordinates summed into the round accumulator.  Integer addition is
-exact and commutative, so the accumulated sum — and therefore the round
-mean — is bit-identical under any arrival order, any receive/drain
-interleaving, and any drain batching.
+Drain path (:meth:`AggServer.drain`): a sealed payload's words go to the
+device as it enters the pending store (:meth:`AggServer._stage_pending`:
+one asynchronous rank-1 put, a higher attempt's replacing the one it
+supersedes, an expired client's dropped), so the copy overlaps the receive
+of the clients after it.  The drain stacks those rows on the device, in
+client-id order with zero rows padding the sender axis to the kernel's
+block, and decodes all pending payloads of one color space q against the
+server's decode reference in ONE batched Pallas launch
+(repro.kernels.ops.lattice_decode_batched), verifies their §5 coordinate
+checksums vectorized, and sums the accepted senders' integer lattice
+coordinates into the round accumulator.  ``RoundStats.staged_on_arrival``
+counts the payloads drained from rows put there on arrival,
+``staged_at_drain`` those the drain had to put there itself.  Integer
+addition is exact and commutative, so the accumulated sum — and therefore
+the round mean — is bit-identical under any arrival order, any
+receive/drain interleaving, and any drain batching.
 
 Anchored rounds (RoundSpec v2, ``anchor_digest != 0``): clients encoded
 ``x - anchor``, so the server operates entirely in anchor-relative space —
@@ -89,6 +97,9 @@ class RoundStats:
                                        # below one body per pending client)
     fold_syncs: int = 0          # the streaming fold's blocking device-to-
                                  # host readbacks (one per completed stream)
+    staged_on_arrival: int = 0   # drained payloads whose words were put on
+                                 # the device as they entered the store
+    staged_at_drain: int = 0     # drained payloads put there by the drain
     max_dist: float = 0.0        # max |decoded - ref|_inf over accepts
     dist_b: Optional[np.ndarray] = None    # (nb,) per-bucket max distance
     fails_b: Optional[np.ndarray] = None   # (nb,) per-bucket failure counts
@@ -126,17 +137,19 @@ def _commit_math(r: Array, ksum: Array, k0: Array, weights: Array, u: Array,
 
 
 @partial(jax.jit, static_argnames=("q", "bucket"))
-def _drain_math(words: Array, sides: Array, checks: Array, valid: Array,
-                anchor: Array, u: Array, weights: Array, y_col: Array,
-                m: Array, k0: Array, *, q: int, bucket: int):
+def _drain_math(rows: "tuple[Array, ...]", sides: Array, checks: Array,
+                valid: Array, anchor: Array, u: Array, weights: Array,
+                y_col: Array, m: Array, k0: Array, *, q: int, bucket: int):
     """Decode S payloads, verify checksums, sum accepted integer coords.
 
-    words: (S, nw) uint32; sides: (S, nb) f32 sidecars; checks: (S,) uint32;
-    valid: (S,) bool (False for the block-size padding rows the server adds
-    so drain sizes hit a bounded set of compiled shapes); anchor/u/weights:
-    (n,); y_col: (nb,) decode margins at this q; m: (S,) int32 n_summed of
-    each payload (1 for an ordinary client); k0: (n,) int32 the round's
-    decode-reference coordinates (:func:`repro.agg.rounds.decode_ref_coords`).
+    rows: S (nw,) uint32 packed words, one array a payload, stacked here
+    into the decode's (S, nw) input; sides: (S, nb) f32 sidecars; checks:
+    (S,) uint32; valid: (S,) bool (False for the block-size padding rows the
+    server adds so drain sizes hit a bounded set of compiled shapes);
+    anchor/u/weights: (n,); y_col: (nb,) decode margins at this q; m: (S,)
+    int32 n_summed of each payload (1 for an ordinary client); k0: (n,)
+    int32 the round's decode-reference coordinates
+    (:func:`repro.agg.rounds.decode_ref_coords`).
 
     A combined payload from a tree tier (m > 1) carries ``K' = k0 + sum_i
     r_i`` — the tier folded m clients' residuals about k0 — so the true
@@ -150,6 +163,7 @@ def _drain_math(words: Array, sides: Array, checks: Array, valid: Array,
     payloads (m == 1): a combined payload's distance-to-reference scales
     like m*y and would poison the y-tracking margins.
     """
+    words = jnp.stack(rows)                                # (S, nw)
     s_sender = jnp.repeat(sides, bucket, axis=-1)          # (S, n)
     k = K.lattice_decode_batched(words, anchor, u, s_sender, q=q,
                                  mode="coords")            # (S, n) int32
@@ -233,6 +247,23 @@ class AggServer(Intake):
         self._count = 0
         self._max_abs_k = 0
         self._publish_traced = False
+        # sealed payloads' words on the device, put there as each entered
+        # the pending store: client id -> (payload, (nw,) uint32 array)
+        self._staged: "dict[int, tuple[wire.Payload, Array]]" = {}
+        self._zero_rows: "dict[int, Array]" = {}   # padding row, by nw
+
+    def _stage_pending(self, p: wire.Payload) -> None:
+        """Start the words' copy to the device while the host goes on
+        receiving: one asynchronous rank-1 put (no host-side tiling), which
+        replaces the array of a payload this one supersedes.  ``p.words``
+        views the sealed body, which nothing writes again."""
+        with _obs.span("stage.put"):
+            self._staged[p.client_id] = (p, jax.device_put(p.words))
+
+    def expire_client(self, client_id: int) -> None:
+        """:meth:`Intake.expire_client`, dropping the staged array too."""
+        super().expire_client(client_id)
+        self._staged.pop(client_id, None)
 
     def _commit_stream(self, h: wire.FrameHeader, p: wire.Payload,
                        rec: _StreamFold) -> wire.Response:
@@ -322,15 +353,20 @@ class AggServer(Intake):
             by_q: dict[int, list[wire.Payload]] = {}
             for p in self._take_pending():
                 by_q.setdefault(p.q, []).append(p)
+            staged, self._staged = self._staged, {}
             responses = []
             for q, plist in sorted(by_q.items()):
-                responses += self._drain_q(q, plist)
+                responses += self._drain_q(q, plist, staged)
             region.note(accepted=len(self._accepted))
         return responses + self._resend_requests()
 
-    def _drain_q(self, q: int, plist: "list[wire.Payload]") -> "list[bytes]":
+    def _drain_q(self, q: int, plist: "list[wire.Payload]",
+                 staged: "dict[int, tuple[wire.Payload, Array]]"
+                 ) -> "list[bytes]":
         """One batched decode of the pending payloads of color space ``q``
-        (in client-id order); returns their ACK/NACK/REJECT responses."""
+        (in client-id order), their words taken from ``staged`` (the
+        device arrays put there on arrival, popped as used); returns their
+        ACK/NACK/REJECT responses."""
         # pad the sender axis to the kernel's block size so drain sizes map
         # onto a bounded set of compiled shapes (padding rows carry
         # valid=False and never enter the sum)
@@ -338,8 +374,21 @@ class AggServer(Intake):
         pad = (-S) % DEFAULT_BLOCK_SENDERS
         attempt0 = plist[0].attempt
         with _obs.span("agg.stage"):
-            words = jnp.asarray(np.pad(
-                np.stack([p.words for p in plist]), ((0, pad), (0, 0))))
+            rows, n_arrived = [], 0
+            for p in plist:
+                p_staged, row = staged.pop(p.client_id, (None, None))
+                if p_staged is p:
+                    n_arrived += 1
+                else:       # no array of its own: to the device now
+                    row = jax.device_put(p.words)
+                rows.append(row)
+            self._obs.inc("staged_on_arrival", n_arrived)
+            self._obs.inc("staged_at_drain", S - n_arrived)
+            nw = plist[0].words.shape[-1]
+            zero = self._zero_rows.get(nw)
+            if zero is None:
+                zero = self._zero_rows[nw] = jnp.zeros((nw,), jnp.uint32)
+            rows = tuple(rows) + (zero,) * pad
             sides = jnp.asarray(np.pad(
                 np.stack([p.sides for p in plist]), ((0, pad), (0, 0)),
                 constant_values=1.0))
@@ -354,9 +403,10 @@ class AggServer(Intake):
         with _obs.span("agg.decode"):
             (ok, ksum_delta, count_delta, max_dist, dist_b, fails_b,
              max_abs_k) = \
-                _drain_math(words, sides, checks, valid, self._ref_flat,
+                _drain_math(rows, sides, checks, valid, self._ref_flat,
                             self._u.reshape(-1), self._weights, y_col, m,
                             self._k0, q=q, bucket=self.spec.cfg.bucket)
+            del rows    # the decode's stack holds the words from here on
             ok = np.asarray(ok)[:S]
             n_clients = int(count_delta)    # accepts plus tier fan-in (m > 1)
             max_abs_k = int(max_abs_k)

@@ -129,6 +129,34 @@ def test_stream_fold_compiles_in_place(one_chip, q):
         arg((N,), F32), m=1, bucket=bucket).compile()
 
 
+@pytest.mark.parametrize("senders", [256, 32])
+def test_sealed_drain_compiles_from_staged_rows(one_chip, monkeypatch,
+                                                senders):
+    """The sealed drain at the fl-xdevice cells' size (d = 1.4M in 342
+    buckets of 4096, q = 16) takes one (nw,) uint32 array a payload, as the
+    server puts them on the device on arrival, and stacks them there: it
+    compiles for the chip with the decode as its one Mosaic launch, at a
+    full 256-sender round and at 32 senders (17 to 32 pad to it)."""
+    from repro.agg.server import _drain_math
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    nb, bucket = 342, 4096
+    n = nb * bucket
+    nw = n * L.bits_for_q(16) // 32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = tuple(arg((nw,), U32) for _ in range(senders))
+    text = _drain_math.lower(
+        rows, arg((senders, nb), F32), arg((senders,), U32),
+        arg((senders,), jnp.bool_), arg((n,), F32), arg((n,), F32),
+        arg((n,), U32), arg((nb,), F32), arg((senders,), jnp.int32),
+        arg((n,), jnp.int32), q=16, bucket=bucket).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "concatenate" in text
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
 def test_expert_grouped_matmul_compiles(one_chip, k, n):
     """The held experts' grouped products at Moonlight's widths (D 2048 ->

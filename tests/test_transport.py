@@ -5,6 +5,7 @@ import dataclasses
 import struct
 import zlib
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -805,6 +806,247 @@ def test_streaming_distance_telemetry_matches_sealed_drain():
     np.testing.assert_allclose(st.dist_b, se.dist_b, rtol=1e-6, atol=0)
     np.testing.assert_allclose(st.max_dist, se.max_dist, rtol=1e-6, atol=0)
     assert not st.fails_b.any() and not se.fails_b.any()
+
+
+# ---------------------------------------------------------------------------
+# Sealed payloads staged on the device as they arrive
+# ---------------------------------------------------------------------------
+
+def _payload_of(frames):
+    """A client's payload rebuilt by hand from its chunk frames."""
+    parts = sorted((wire.decode_frame(f) for f in frames),
+                   key=lambda hc: hc[0].chunk_index)
+    return wire.payload_from_body(parts[0][0],
+                                  b"".join(c for _, c in parts))
+
+
+def _host_staged_reference(spec, base, payloads):
+    """The unstaged reference: ``payloads`` drained as the sealed drain did
+    before staging on arrival, each color space's words stacked and padded
+    on the host and copied to the device as one (S + pad, nw) array, then
+    decoded by ``_drain_math``.  Returns (ksum, count, mean)."""
+    from repro.agg import server as SV
+    from repro.kernels.lattice_decode import DEFAULT_BLOCK_SENDERS
+    ctx = AggServer(spec, base, streaming=False)
+    ksum = np.zeros((spec.nb, spec.cfg.bucket), np.int32)
+    count = 0
+    by_q = {}
+    for p in sorted(payloads, key=lambda p: p.client_id):
+        by_q.setdefault(p.q, []).append(p)
+    for q, plist in sorted(by_q.items()):
+        S_ = len(plist)
+        pad = (-S_) % DEFAULT_BLOCK_SENDERS
+        words = jnp.asarray(np.pad(np.stack([p.words for p in plist]),
+                                   ((0, pad), (0, 0))))
+        out = SV._drain_math(
+            tuple(words),
+            np.pad(np.stack([p.sides for p in plist]), ((0, pad), (0, 0)),
+                   constant_values=1.0),
+            np.pad(np.array([p.check for p in plist], np.uint32), (0, pad)),
+            np.arange(S_ + pad) < S_, ctx._ref_flat, ctx._u.reshape(-1),
+            ctx._weights, wire.y_buckets_at_attempt(spec, plist[0].attempt),
+            np.pad(np.array([p.n_summed for p in plist], np.int32), (0, pad),
+                   constant_values=1),
+            ctx._k0, q=q, bucket=spec.cfg.bucket)
+        assert np.asarray(out[0])[:S_].all()
+        ksum += np.asarray(out[1]).reshape(ksum.shape)
+        count += int(out[2])
+    mean_b = SV._mean_math(jnp.asarray(ksum), jnp.int32(count), ctx._u,
+                           ctx._sides[:, None])
+    if spec.anchored:
+        mean_b = mean_b + ctx._anchor_b
+    return ksum, count, np.asarray(rounds.unbucketize(mean_b, spec))
+
+
+def _assert_matches_reference(server, mean, ref):
+    ksum, count, mean_ref = ref
+    assert np.array_equal(np.asarray(server._ksum), ksum)
+    assert server._count == count
+    assert np.array_equal(mean.view(np.uint32), mean_ref.view(np.uint32))
+
+
+@pytest.fixture
+def drain_spy(monkeypatch):
+    """Records each ``_drain_math`` launch: (q, the rows as host arrays,
+    the row objects)."""
+    from repro.agg import server as SV
+    calls, orig = [], SV._drain_math
+
+    def spy(rows, *args, q, bucket):
+        calls.append((q, [np.asarray(r) for r in rows], rows))
+        return orig(rows, *args, q=q, bucket=bucket)
+    monkeypatch.setattr(SV, "_drain_math", spy)
+    return calls
+
+
+def test_staged_on_arrival_bit_parity_shuffled_arrival():
+    """A chunked sealed round under shuffled chunk arrival: every payload's
+    words are staged as it completes and the drain's mean and integer sum
+    are bit-identical to the host-stacked reference."""
+    spec = _spec(d=2048, bucket=256, mtu=300)
+    base, _, fleets = _fleet(spec, 5)
+    assert len(fleets[0]) > 2
+    ref = _host_staged_reference(spec, base,
+                                 [_payload_of(fs) for fs in fleets])
+    flat = [f for fs in fleets for f in fs]
+    for trial in range(3):
+        order = np.random.RandomState(trial).permutation(len(flat))
+        server = AggServer(spec, base)
+        assert not server._streaming
+        for i in order:
+            server.receive(flat[i])
+        assert sorted(server._staged) == list(range(5))
+        mean, stats = server.finalize()
+        assert server.accepted_clients == frozenset(range(5)), trial
+        assert (stats.staged_on_arrival, stats.staged_at_drain) == (5, 0)
+        assert not server._staged
+        _assert_matches_reference(server, mean, ref)
+
+
+def test_staged_on_arrival_replaced_by_higher_attempt(drain_spy):
+    """A client whose pending payload is replaced by a higher attempt: the
+    replacement's array replaces the staged one, its words are summed and
+    the old ones are not, and the client is accepted once."""
+    spec = _spec(d=2048, bucket=256, mtu=300)
+    base, xs, fleets = _fleet(spec, 3)
+    other = AggClient(spec, 0, xs[0] + 0.05).frames(1)   # other words
+    server = AggServer(spec, base)
+    for f in fleets[0]:
+        server.receive(f)
+    assert server._staged[0][0].attempt == 0
+    for f in other:
+        server.receive(f)
+    assert server._staged[0][0].attempt == 1
+    for fs in fleets[1:]:
+        for f in fs:
+            server.receive(f)
+    mean, stats = server.finalize()
+    assert server.accepted_clients == frozenset(range(3))
+    assert stats.accepted == 3
+    assert (stats.staged_on_arrival, stats.staged_at_drain) == (3, 0)
+    new = _payload_of(other)
+    [(q_new, rows_new, _)] = [c for c in drain_spy if c[0] == new.q]
+    assert np.array_equal(rows_new[0], new.words)
+    _assert_matches_reference(server, mean, _host_staged_reference(
+        spec, base, [new] + [_payload_of(fs) for fs in fleets[1:]]))
+
+
+def test_staged_on_arrival_expired_client_never_summed():
+    """An expired pending client's staged array is dropped at once and its
+    words never reach the sum."""
+    spec = _spec(d=2048, bucket=256, mtu=300)
+    base, _, fleets = _fleet(spec, 4)
+    server = AggServer(spec, base)
+    for fs in fleets:
+        for f in fs:
+            server.receive(f)
+    server.expire_client(2)
+    assert 2 not in server._staged and 2 not in server._pending
+    mean, stats = server.finalize()
+    assert server.accepted_clients == frozenset({0, 1, 3})
+    assert (stats.expired, stats.staged_on_arrival) == (1, 3)
+    assert not server._staged
+    _assert_matches_reference(server, mean, _host_staged_reference(
+        spec, base, [_payload_of(fleets[i]) for i in (0, 1, 3)]))
+
+
+def test_staged_on_arrival_two_color_spaces_one_launch_each(drain_spy):
+    """Payloads at two escalation levels drain in one launch per color
+    space q, each from its own staged rows, bit-identical to the
+    reference."""
+    spec = _spec(d=2048, bucket=256, mtu=300)
+    base, xs, fleets = _fleet(spec, 5)
+    fleets = fleets[:3] + [AggClient(spec, i, xs[i]).frames(1)
+                           for i in (3, 4)]
+    server = AggServer(spec, base)
+    for fs in fleets:
+        for f in fs:
+            server.receive(f)
+    mean, stats = server.finalize()
+    assert server.accepted_clients == frozenset(range(5))
+    assert (stats.staged_on_arrival, stats.staged_at_drain) == (5, 0)
+    payloads = [_payload_of(fs) for fs in fleets]
+    qs = sorted({p.q for p in payloads})
+    assert len(qs) == 2
+    assert [c[0] for c in drain_spy] == qs
+    for q, rows, _ in drain_spy:
+        want = [p.words for p in payloads if p.q == q]
+        assert all(np.array_equal(r, w) for r, w in zip(rows, want))
+    _assert_matches_reference(server, mean,
+                              _host_staged_reference(spec, base, payloads))
+
+
+def test_staged_on_arrival_pads_17_senders_with_zero_rows(drain_spy):
+    """S = 17 single-frame payloads drain in one launch of 32 rows: the 17
+    staged rows in client-id order, then one cached zero row 15 times."""
+    spec = _spec(d=2048, bucket=256, mtu=0)
+    base, _, fleets = _fleet(spec, 17)
+    server = AggServer(spec, base)
+    for i in np.random.RandomState(1).permutation(17):
+        server.receive(fleets[i][0])
+    mean, stats = server.finalize()
+    assert server.accepted_clients == frozenset(range(17))
+    assert (stats.staged_on_arrival, stats.staged_at_drain) == (17, 0)
+    [(_, rows, objs)] = drain_spy
+    payloads = [_payload_of(fs) for fs in fleets]
+    assert len(rows) == 32
+    assert all(np.array_equal(r, p.words) for r, p in zip(rows, payloads))
+    assert len({id(o) for o in objs[17:]}) == 1 and not rows[17].any()
+    _assert_matches_reference(server, mean,
+                              _host_staged_reference(spec, base, payloads))
+
+
+def test_staged_counters_sealed_streaming_and_fallback():
+    """``staged_on_arrival`` counts a sealed round's accepted payloads and
+    ``staged_at_drain`` none; a streaming round stages nothing; a pending
+    payload with no staged array, or whose staged array came from another
+    payload, is put on the device by the drain, with the same mean."""
+    spec = _spec(d=2048, bucket=256, mtu=300, window=2)
+    base, _, fleets = _fleet(spec, 4)
+    means = {}
+    for mode in ("sealed", "streaming", "fallback"):
+        server = AggServer(spec, base, streaming=mode == "streaming")
+        for fs in fleets:
+            for f in fs:
+                server.receive(f)
+        if mode == "fallback":
+            for cid in (0, 1):
+                del server._staged[cid]
+            for cid in (2, 3):      # stale: never summed
+                server._staged[cid] = (object(), jnp.zeros_like(
+                    server._staged[cid][1]))
+        means[mode], stats = server.finalize()
+        assert stats.accepted == 4
+        assert (stats.staged_on_arrival, stats.staged_at_drain) == {
+            "sealed": (4, 0), "streaming": (0, 0),
+            "fallback": (0, 4)}[mode]
+    for mode in ("streaming", "fallback"):
+        assert np.array_equal(means[mode].view(np.uint32),
+                              means["sealed"].view(np.uint32)), mode
+
+
+def test_tier_batched_drain_stages_nothing_on_the_device(monkeypatch):
+    """The tree tier drains on the host: its intake's staging hook puts
+    nothing on the device (the flat server, as a control, puts one array
+    a payload)."""
+    import jax
+
+    from repro.agg.tree import TIER_ID_BASE, TierAggregator
+    spec = _spec(d=2048, bucket=256, mtu=300)
+    base, _, fleets = _fleet(spec, 3)
+    puts, orig = [], jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda x, *a, **k: puts.append(x) or orig(x, *a, **k))
+    for node, n_puts in ((TierAggregator(spec, base, TIER_ID_BASE), 0),
+                         (AggServer(spec, base), 3)):
+        puts.clear()
+        for fs in fleets:
+            for f in fs:
+                node.receive(f)
+        assert len(puts) == n_puts, type(node).__name__
+        node.tick()
+        assert node.accepted_clients == frozenset(range(3))
+        assert len(puts) == n_puts, type(node).__name__
 
 
 def test_send_window_paces_and_counts_stalls():
